@@ -7,7 +7,9 @@ One scenario family, written to ``BENCH_fleet.json``:
   two ways: (a) a single in-process ``YCHGService`` behind its own
   ``ServerThread`` (today's one-process ceiling) and (b) the
   ``repro.fleet`` router fanning over ``--workers`` subprocess workers.
-  Both arms are warmed on a DISJOINT warm mask set (same bucket, so the
+  The fleet arm runs first and the single-process arm after its workers
+  are gone, so no two processes ever claim one chip. Both arms are
+  warmed on a DISJOINT warm mask set (same bucket, so the
   ladder rungs compile outside timing, but no timed mask is ever served
   from a cache). The row records throughput for both arms, the ratio,
   and a bit-identity verdict (every field of every result compared
@@ -40,11 +42,13 @@ import numpy as np
 
 import jax
 
+from repro.core import serial
 from repro.data import modis
 from repro.engine import Engine
 from repro.fleet import FleetRouter, FleetSupervisor, HashRing, RouterConfig, RouterThread
 from repro.fleet.router import routing_key
 from repro.frontend import ServerThread, YCHGClient
+from repro.launch.compilecache import enable_compile_cache
 from repro.service import ServiceConfig, YCHGService
 
 RES = 64
@@ -83,14 +87,9 @@ def run_fleet_vs_single(n_workers: int, n_requests: int) -> dict:
     cfg = ServiceConfig(bucket_sides=(RES,), max_batch=MAX_BATCH,
                         max_delay_ms=2.0)
 
-    # ---- single-process arm (reference results double as the identity bar)
-    with YCHGService(Engine(), cfg) as svc, ServerThread(svc) as srv, \
-            YCHGClient("127.0.0.1", srv.port) as client:
-        list(client.analyze_batch(warm))
-        single_s, single_items = _timed_batch(client, timed)
-    want = [single_items[i].result for i in range(n_requests)]
-
-    # ---- fleet arm: router over n_workers subprocess workers
+    # ---- fleet arm first: router over n_workers subprocess workers. This
+    # process stays off JAX's backend until the workers are gone, since on
+    # a TPU host each worker owns a chip.
     worker_args = ["--buckets", str(RES), "--max-batch", str(MAX_BATCH),
                    "--max-delay-ms", "2.0", "--cache-entries", "1024"]
     sup = FleetSupervisor(n_workers, worker_args=worker_args)
@@ -107,18 +106,18 @@ def run_fleet_vs_single(n_workers: int, n_requests: int) -> dict:
             client.wait_ready(timeout=180.0)
             list(client.analyze_batch(warm))
             fleet_s, fleet_items = _timed_batch(client, timed)
-            bit_identical = _identical(fleet_items, want)
 
             # ---- peering leg: kill a mask's owner, reroute (survivor
             # caches it), restart the slot, repeat -> sibling-cache hit
             ring = HashRing([l.name for l in links])
             probe = timed[0]
+            want_probe = serial.analyze_numpy(probe)
             owner = ring.node_for(routing_key(probe))
             sup._by_name[owner].process.kill()
             got = client.analyze(probe)                 # reroutes
             assert all(
-                np.array_equal(np.asarray(want[0][f]), got[f])
-                for f in want[0]), "rerouted result not identical"
+                np.array_equal(np.asarray(want_probe[f]), got[f])
+                for f in want_probe), "rerouted result not identical"
             asyncio.run_coroutine_threadsafe(
                 router.check_workers(), rt._loop).result(timeout=300)
             client.analyze(probe)                       # restarted owner peers
@@ -127,6 +126,14 @@ def run_fleet_vs_single(n_workers: int, n_requests: int) -> dict:
                     peer_hits = float(line.rsplit(" ", 1)[1])
     finally:
         sup.stop()
+
+    # ---- single-process arm (its results are the identity bar)
+    with YCHGService(Engine(), cfg) as svc, ServerThread(svc) as srv, \
+            YCHGClient("127.0.0.1", srv.port) as client:
+        list(client.analyze_batch(warm))
+        single_s, single_items = _timed_batch(client, timed)
+    want = [single_items[i].result for i in range(n_requests)]
+    bit_identical = _identical(fleet_items, want)
 
     assert bit_identical, "fleet arm not bit-identical to single process"
     assert peer_hits > 0, "repeat traffic after restart never hit a sibling"
@@ -162,6 +169,7 @@ def main() -> None:
     ap.add_argument("--workers", type=int, default=4)
     ap.add_argument("--requests", type=int, default=32)
     args = ap.parse_args()
+    enable_compile_cache()
     row = run_fleet_vs_single(args.workers, args.requests)
     print(json.dumps(row), flush=True)
     report = {

@@ -42,6 +42,7 @@ import jax
 from repro.data import modis
 from repro.engine import Engine
 from repro.frontend import ServerThread, YCHGClient
+from repro.launch.compilecache import enable_compile_cache
 from repro.service import ServiceConfig, ServiceOverloaded, YCHGService
 
 
@@ -212,6 +213,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_frontend.json")
     args = ap.parse_args()
+    enable_compile_cache()
     rows = [run_wire_vs_inprocess(), run_fair_vs_unfair_skew()]
     for row in rows:
         print(json.dumps(row), flush=True)

@@ -48,6 +48,7 @@ from repro.data import modis
 from repro.engine import Engine
 from repro.engine.ops import get_op, op_names
 from repro.frontend import ServerThread, YCHGClient
+from repro.launch.compilecache import enable_compile_cache
 from repro.service import Service, ServiceConfig
 
 RES = 64
@@ -174,6 +175,7 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_ops.json")
     ap.add_argument("--requests", type=int, default=24)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ServiceConfig(bucket_sides=(RES,), max_batch=MAX_BATCH,
                         max_delay_ms=2.0)

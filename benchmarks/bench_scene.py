@@ -46,6 +46,7 @@ import jax
 
 from repro.data import scenes
 from repro.engine import Engine
+from repro.launch.compilecache import enable_compile_cache
 from repro.scene import (
     BulkJob,
     BulkJobConfig,
@@ -187,6 +188,7 @@ def main() -> None:
     ap.add_argument("--granules", type=int, default=2)
     ap.add_argument("--repeats", type=int, default=3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     scenarios: List[dict] = []
     print(f"scene_stitch: {args.height}x{args.width}, tile_h {args.tile_h}, "

@@ -41,6 +41,7 @@ import jax
 
 from repro.data import modis
 from repro.engine import Engine
+from repro.launch.compilecache import enable_compile_cache
 from repro.service import (
     ServiceConfig,
     ServiceOverloaded,
@@ -301,6 +302,7 @@ def main() -> None:
                     help="the small scenario set the CI bench-gate runs "
                          "(seconds, not minutes); writes mode='quick'")
     args = ap.parse_args()
+    enable_compile_cache()
     scenarios = QUICK_SCENARIOS if args.quick else SCENARIOS
     extras = (
         {"low_occupancy": lambda: run_low_occupancy(pool_size=10)}

@@ -53,6 +53,7 @@ import jax
 
 from repro.data import modis
 from repro.engine import Engine
+from repro.launch.compilecache import enable_compile_cache
 from repro.service import (
     DeadlineExceeded,
     Service,
@@ -243,6 +244,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_slo.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     engine = Engine()
     cfg = ServiceConfig(

@@ -26,7 +26,7 @@ class EngineSection:
     dtype: Optional[str] = None        # cast masks on ingest (None = as-is)
     mesh_axis: str = "data"            # batch axis when a mesh is attached
     interpret: Optional[bool] = None   # None = interpret off-TPU
-    stream_vmem_budget: int = 4 * 1024 * 1024
+    stream_vmem_budget: int = 1024 * 1024
 
     def to_engine_config(self, **overrides: Any):
         """Materialise as a ``repro.engine.YCHGConfig`` (with overrides)."""

@@ -36,7 +36,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.ychg import YCHGSummary
@@ -60,7 +59,8 @@ class YCHGConfig:
     dtype              optional dtype name masks are cast to on ingest
                        (None = accept as-is; nonzero = foreground either way).
     mesh_axis          batch axis name used when a mesh is attached.
-    interpret          Pallas interpret flag (None = auto: interpret off-TPU).
+    interpret          Pallas interpret flag (None = auto: interpret off-TPU;
+                       True is refused on a TPU, where the kernels compile).
     stream_vmem_budget raw-tile bytes past which the fused/colscan kernels
                        switch to the H-streamed variant (VMEM threshold).
     """
@@ -71,7 +71,7 @@ class YCHGConfig:
     dtype: Optional[str] = None
     mesh_axis: str = "data"
     interpret: Optional[bool] = None
-    stream_vmem_budget: int = 4 * 1024 * 1024
+    stream_vmem_budget: int = 1024 * 1024
 
 
 # the knobs are op-agnostic; EngineConfig is the preferred spelling going
@@ -175,11 +175,15 @@ class Engine:
                 f"mesh has axes {mesh.axis_names}, config.mesh_axis="
                 f"{config.mesh_axis!r}"
             )
+        # platform is fixed per process; cache it out of the hot dispatch path
+        self._platform = jax.default_backend()
+        if config.interpret and self._platform == "tpu":
+            raise ValueError(
+                "interpret=True on a TPU would evaluate the Pallas kernels in "
+                "Python instead of compiling them; leave interpret=None")
         self.config = config
         self.op = op
         self.mesh = mesh
-        # platform is fixed per process; cache it out of the hot dispatch path
-        self._platform = jax.default_backend()
         self._cast_dtype = None if config.dtype is None else jnp.dtype(config.dtype)
         # op -> (registry generation, resolved spec) — revalidated against
         # registry.generation() so late register_backend() calls still apply
@@ -366,8 +370,8 @@ class Engine:
             return tuple(getattr(s, f) for f in fields)
 
         pspec = P(axis)
-        outs = shard_map(local, mesh=self.mesh, in_specs=pspec,
-                         out_specs=pspec, check_rep=False)(x)
+        outs = jax.shard_map(local, mesh=self.mesh, in_specs=pspec,
+                             out_specs=pspec, check_vma=False)(x)
         return opspec.summary_type(*(o[:b] for o in outs))
 
     # ------------------------------------------------------------ tooling

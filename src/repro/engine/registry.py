@@ -32,7 +32,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-import warnings
 from typing import Callable, Mapping, Optional, Tuple
 
 from repro.obs.histogram import DISPATCH_BOUNDS, Histogram, HistogramSnapshot
@@ -195,9 +194,9 @@ def resolve(backend: str, *, platform: str, need_mesh: bool = False,
     run on ``platform`` (and, when a mesh is attached, that is
     mesh-capable). Explicit names are honoured as-is except that
     ``need_mesh`` rejects backends that cannot be shard_mapped. An op that
-    is registered but has no backend claiming the current platform falls
-    back to its best batch-capable backend with a warning — never a bare
-    KeyError; an op nobody registered raises :class:`UnknownOpError`.
+    is registered but has no backend claiming the current platform raises
+    ``ValueError`` naming the platform — no backend of another platform is
+    substituted; an op nobody registered raises :class:`UnknownOpError`.
     """
     if op not in registered_ops():
         raise UnknownOpError(
@@ -212,28 +211,14 @@ def resolve(backend: str, *, platform: str, need_mesh: bool = False,
                 f"{tuple(n for (o, n), s in sorted(_REGISTRY.items()) if o == op and s.supports_mesh)}"
             )
         return spec
-    pool = [
+    candidates = [
         s for s in _REGISTRY.values()
         if s.op == op
         and s.supports_batch
         and (s.supports_mesh or not need_mesh)
+        and platform in s.device_kinds
     ]
-    candidates = [s for s in pool if platform in s.device_kinds]
     if not candidates:
-        if pool:
-            # registered op, no backend claims this platform: pick the best
-            # batch-capable spec anyway (interpret-mode backends are exact
-            # everywhere) and say so, rather than dying on a lookup error
-            best = max(pool, key=lambda s: (max(s.priority.values(),
-                                                default=0), s.name))
-            warnings.warn(
-                f"op {op!r} has no backend registered for platform "
-                f"{platform!r}; falling back to backend {best.name!r} "
-                f"(device_kinds={best.device_kinds})",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            return best
         raise ValueError(
             f"no registered backend for op {op!r} can run on platform "
             f"{platform!r} (need_mesh={need_mesh}); registered: "

@@ -32,10 +32,12 @@ own counters into one page: worker ``*_total`` series are summed
 from __future__ import annotations
 
 import asyncio
+import collections
 import dataclasses
 import http.client
 import json
 import math
+import os
 import subprocess
 import sys
 import threading
@@ -46,6 +48,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.engine.ops import op_names
+from repro.fleet.chips import host_tpu_chips, pinned_env
 from repro.fleet.hashring import HashRing
 from repro.fleet.worker import parse_ready_line
 from repro.frontend import protocol
@@ -143,6 +146,8 @@ class WorkerLink:
     http_port: int
     process: Optional[subprocess.Popen] = None
     up: bool = True
+    chip: Optional[int] = None   # TPU chip the slot is pinned to
+    device: str = ""             # what the worker reported at READY
 
 
 @dataclasses.dataclass
@@ -805,6 +810,9 @@ class FleetRouter:
 
 # ------------------------------------------------------------- supervision
 
+_SRC_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))   # .../src holding the repro package
+
 
 class FleetSupervisor:
     """Spawn and respawn worker processes under stable slot names.
@@ -812,19 +820,30 @@ class FleetSupervisor:
     Workers bind ephemeral ports and hand them back through the one-line
     ``WORKER READY`` handshake on stdout; a restart keeps the slot name
     (ring placement) and updates the link's ports in place, so the
-    router's tables never go stale."""
+    router's tables never go stale.
+
+    On a TPU host each slot owns one chip (``fleet.chips``): slot i is
+    pinned to chip i, a restart reclaims the same chip, and a fleet larger
+    than the host's chip count is refused. The parent never starts the
+    TPU runtime for this. A worker's stderr is kept (its last lines) so a
+    worker that dies before its handshake says why."""
 
     def __init__(self, n: int, *, host: str = "127.0.0.1",
                  worker_args: Sequence[str] = (),
                  start_timeout_s: float = 180.0):
         if n < 1:
             raise ValueError(f"fleet size must be >= 1, got {n}")
+        chips = host_tpu_chips()
+        if chips and n > chips:
+            raise ValueError(
+                f"fleet of {n} workers on a host with {chips} TPU chips: "
+                f"a chip serves one process, so at most {chips} workers")
         self.host = host
         self.worker_args = list(worker_args)
         self.start_timeout_s = start_timeout_s
         self.links: List[WorkerLink] = [
             WorkerLink(name=f"w{i}", host=host, rpc_port=0, http_port=0,
-                       up=False)
+                       up=False, chip=i if chips else None)
             for i in range(n)]
         self._by_name = {l.name: l for l in self.links}
 
@@ -834,27 +853,43 @@ class FleetSupervisor:
         return self.links
 
     def _spawn(self, link: WorkerLink) -> None:
+        env = dict(os.environ)
+        # workers import this same repro package, wherever it was found
+        env["PYTHONPATH"] = os.pathsep.join(
+            p for p in (_SRC_ROOT, env.get("PYTHONPATH")) if p)
+        if link.chip is not None:
+            env.update(pinned_env(link.chip))
         proc = subprocess.Popen(
             [sys.executable, "-m", "repro.fleet.worker",
              "--host", self.host, "--port", "0", "--rpc-port", "0",
              *self.worker_args],
-            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True)
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+            env=env)
+        # drain stderr for the worker's whole life (a full pipe would
+        # block it) and keep the tail for the failure message
+        tail: "collections.deque[str]" = collections.deque(maxlen=40)
+        drain = threading.Thread(target=tail.extend, args=(proc.stderr,),
+                                 name=f"{link.name}-stderr", daemon=True)
+        drain.start()
         deadline = time.monotonic() + self.start_timeout_s
-        ports = None
+        ready = None
         assert proc.stdout is not None
         while time.monotonic() < deadline:
             line = proc.stdout.readline()
             if not line:
                 break   # worker died before handshaking
-            ports = parse_ready_line(line)
-            if ports is not None:
+            ready = parse_ready_line(line)
+            if ready is not None:
                 break
-        if ports is None:
+        if ready is None:
             proc.kill()
             proc.wait(timeout=10)
+            drain.join(timeout=5)
             raise RuntimeError(
-                f"worker {link.name} never printed its READY handshake")
-        link.rpc_port, link.http_port = ports
+                f"worker {link.name} (chip {link.chip}) never printed its "
+                f"READY handshake; its stderr ended with:\n"
+                + "".join(tail))
+        link.rpc_port, link.http_port, link.device = ready
         link.process = proc
         link.up = True
 

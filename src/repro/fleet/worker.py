@@ -7,7 +7,11 @@ so local misses consult siblings before computing. The supervisor spawns
 workers with ephemeral ports (0) and parses the one-line handshake this
 process prints once both listeners are bound::
 
-    WORKER READY rpc=<port> http=<port>
+    WORKER READY rpc=<port> http=<port> device=<platform>x<count>[:chip<i>]
+
+``device`` names what JAX gave this worker: on a TPU host the supervisor
+pins each worker to one chip (``fleet.chips``), so it reads
+``tpux1:chip<i>``.
 
 SIGTERM (and SIGINT) drain the service before exit, so an orderly fleet
 shutdown never abandons admitted requests.
@@ -24,21 +28,32 @@ import threading
 READY_PREFIX = "WORKER READY"
 
 
-def ready_line(rpc_port: int, http_port: int) -> str:
-    return f"{READY_PREFIX} rpc={rpc_port} http={http_port}"
+def ready_line(rpc_port: int, http_port: int, device: str) -> str:
+    return f"{READY_PREFIX} rpc={rpc_port} http={http_port} device={device}"
 
 
 def parse_ready_line(line: str):
-    """(rpc_port, http_port) out of a handshake line, or None."""
+    """(rpc_port, http_port, device) out of a handshake line, or None."""
     line = line.strip()
     if not line.startswith(READY_PREFIX):
         return None
     try:
         kv = dict(part.split("=", 1)
                   for part in line[len(READY_PREFIX):].split())
-        return int(kv["rpc"]), int(kv["http"])
+        return int(kv["rpc"]), int(kv["http"]), kv.get("device", "")
     except (KeyError, ValueError):
         return None
+
+
+def device_label() -> str:
+    """``<platform>x<count>`` of the devices JAX gives this worker, plus
+    ``:chip<i>`` when the supervisor pinned it to chip i (a pinned
+    worker's own device ids and coordinates read 0 whatever its chip)."""
+    import jax
+
+    devs = jax.devices()
+    chip = os.environ.get("TPU_VISIBLE_CHIPS")
+    return f"{devs[0].platform}x{len(devs)}" + (f":chip{chip}" if chip else "")
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -58,10 +73,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--max-queue-depth", type=int, default=None)
     ap.add_argument("--bucket-queue-depth", type=int, default=None)
     ap.add_argument("--policy", default="block", choices=["block", "shed"])
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="share a persistent JAX compilation cache (a "
-                         "restarted worker reloads its bucket ladder's "
-                         "compiles from disk instead of recompiling)")
     ap.add_argument("--trace-dump", default=None, metavar="PATH",
                     help="dump this worker's flight recorder as Chrome-trace "
                          "JSON to PATH.<pid> on shutdown")
@@ -70,10 +81,10 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
-    if args.compile_cache:
-        from repro.launch.compilecache import enable_compile_cache
+    from repro.launch.compilecache import enable_compile_cache
 
-        enable_compile_cache(args.compile_cache)
+    # a restarted worker reloads its ladder's compiles from the cache
+    enable_compile_cache()
 
     from repro import obs
     from repro.engine import Engine
@@ -102,7 +113,8 @@ def main(argv=None) -> None:
     with Service(Engine(), config, cache=cache) as svc:
         with ServerThread(svc, host=args.host, port=args.port,
                           rpc_port=args.rpc_port) as srv:
-            print(ready_line(srv.rpc_port, srv.port), flush=True)
+            print(ready_line(srv.rpc_port, srv.port, device_label()),
+                  flush=True)
             stop.wait()
             obs.auto_dump("worker-shutdown")
             # context exits drain: ServerThread stops accepting, then

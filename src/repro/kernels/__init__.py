@@ -15,12 +15,15 @@ fused backend). See ``repro.engine`` for the migration table.
                    (one launch per step, HBM round-trip for the counts)
   ychg_fused.py    fused batched pipeline: BOTH steps for a (B, H, W) stack
                    in ONE launch — step 2's diff computed in-register from
-                   step 1's tile result, with a (1, 1) VMEM carry for the
+                   step 1's tile result, with a (1, bw) VMEM carry for the
                    tile seam and revisited accumulator blocks for per-image
                    totals; streamed variant adds an H-tile grid dim with a
                    carry row for images past the VMEM budget
   ychg_packed.py   1-bit row packing (8x less HBM traffic on the scan)
-  ops.py           jit'd wrappers (interpret=True off-TPU);
+  ccl.py, denoise.py  whole-image kernels of the other ops
+  platform.py      interpret-or-compile from the platform, in one place;
+                   scoped-VMEM limits for the whole-image kernels
+  ops.py           jit'd wrappers;
                    ``analyze_fused`` returns a core.ychg.YCHGSummary,
                    bit-identical to core.ychg.analyze
   ref.py           pure-jnp oracles for the exact-equality sweeps

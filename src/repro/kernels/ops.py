@@ -1,12 +1,12 @@
 """jit'd public wrappers for the yCHG Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; interpret
-mode executes the kernel body in Python for correctness validation). On a real
-TPU backend the same calls compile to Mosaic.
+``interpret=None`` resolves from the platform (``kernels.platform``): the
+kernels compile to Mosaic on a TPU and run in interpret mode elsewhere.
 
 The heuristic between the full-column and streamed step-1 kernels is a VMEM
-budget: a full (H, block_w) int8 tile plus boolean temporaries must fit
-comfortably in 16 MiB VMEM; past ~4 MiB for the raw tile we stream over H.
+budget: the kernels widen the (H, block_w) int8 tile to int32 in VMEM, so
+past 1 MiB of raw tile (4 MiB widened, H > 8192 at block_w=128) they
+stream over H in block_h rows instead. The paper's 21000^2 scene streams.
 """
 
 from __future__ import annotations
@@ -23,11 +23,15 @@ from repro.kernels import ychg_fused as _f
 Array = jax.Array
 
 # raw int8 tile budget before switching to the streamed kernel (bytes)
-_FULL_COLUMN_VMEM_BUDGET = 4 * 1024 * 1024
+_FULL_COLUMN_VMEM_BUDGET = 1024 * 1024
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def uses_streamed(h: int, *, block_w: int = 128,
+                  vmem_budget: int | None = None) -> bool:
+    """Whether an image ``h`` rows tall takes the H-streamed kernel."""
+    if vmem_budget is None:
+        vmem_budget = _FULL_COLUMN_VMEM_BUDGET
+    return h * block_w > vmem_budget
 
 
 def colscan_runs(
@@ -39,12 +43,8 @@ def colscan_runs(
     vmem_budget: int | None = None,
 ) -> Array:
     """Step 1: per-column maximal-run counts. (H, W) mask -> (W,) int32."""
-    if interpret is None:
-        interpret = _default_interpret()
-    if vmem_budget is None:
-        vmem_budget = _FULL_COLUMN_VMEM_BUDGET
     h, _ = img.shape
-    if h * block_w > vmem_budget:
+    if uses_streamed(h, block_w=block_w, vmem_budget=vmem_budget):
         return _k.colscan_runs_streamed(
             img, block_w=block_w, block_h=block_h, interpret=interpret
         )
@@ -55,8 +55,6 @@ def transitions(
     runs: Array, *, block_w: int = 128, interpret: bool | None = None
 ) -> tuple[Array, Array, Array]:
     """Step 2: (W,) run counts -> (transitions bool, births i32, deaths i32)."""
-    if interpret is None:
-        interpret = _default_interpret()
     return _k.transitions_pallas(runs, block_w=block_w, interpret=interpret)
 
 
@@ -98,10 +96,6 @@ def analyze_fused(
     images (full column tile over the VMEM budget) stream over H inside the
     same single launch via the carry-row variant.
     """
-    if interpret is None:
-        interpret = _default_interpret()
-    if vmem_budget is None:
-        vmem_budget = _FULL_COLUMN_VMEM_BUDGET
     squeeze = img.ndim == 2
     imgs = img[None] if squeeze else img
     if imgs.ndim != 3:
@@ -111,7 +105,7 @@ def analyze_fused(
         from repro.core import ychg as _ychg
 
         return _ychg.analyze(img)
-    if h * block_w > vmem_budget:
+    if uses_streamed(h, block_w=block_w, vmem_budget=vmem_budget):
         out = _f.fused_analyze_streamed(
             imgs, block_w=block_w, block_h=block_h, interpret=interpret
         )

@@ -14,7 +14,8 @@ Rising-edge detection entirely in registers:
     runs[c]  += popcount(rising)          # lax.population_count (TPU native)
 
 The carry chain down packed rows is a vectorised shift of the MSB column —
-no sequential loop. Step 2 (neighbour diff) is fused into the same pass:
+no sequential loop. In the kernel the bytes are widened to int32 lanes,
+since Mosaic has no 8-bit vector shifts. Step 2 (neighbour diff) is fused into the same pass:
 within a tile, births/deaths come from the tile-local shifted counts; the
 one column per tile boundary is stitched by the wrapper with an O(W/bw)
 vector op, so the fused kernel still makes a single trip over the image.
@@ -27,6 +28,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
+from repro.kernels.ychg_fused import _INT8_SUBLANES
 
 Array = jax.Array
 
@@ -43,64 +48,68 @@ def pack_rows(img: Array) -> Array:
     return jnp.sum(x * weights, axis=1, dtype=jnp.uint8)
 
 
+def _packed_runs(pk_ref):
+    """Packed (Hp, bw) uint8 block -> (1, bw) int32 run counts.
+
+    Bytes widen to int32 lanes (Mosaic has no 8-bit shifts); the carry
+    row comes from a sublane roll with row 0 zeroed."""
+    b = pk_ref[...].astype(jnp.int32)
+    # carry: MSB of the byte above, placed at bit 0 of this byte's row
+    row = jax.lax.broadcasted_iota(jnp.int32, b.shape, 0)
+    carry = jnp.where(row == 0, 0, pltpu.roll(b >> 7, 1, 0))
+    prev = ((b << 1) | carry) & 0xFF
+    rising = b & (prev ^ 0xFF)
+    return jnp.sum(jax.lax.population_count(rising), axis=0, keepdims=True)
+
+
 def _packed_colscan_kernel(pk_ref, runs_ref):
     """Block: packed (Hp, bw) uint8 -> runs (1, bw) int32."""
-    b = pk_ref[...]
-    # carry: MSB of the byte above, placed at bit 0 of this byte's row
-    msb = (b >> 7).astype(jnp.uint8)
-    carry = jnp.concatenate([jnp.zeros_like(msb[:1]), msb[:-1]], axis=0)
-    prev = ((b << 1) | carry).astype(jnp.uint8)
-    rising = (b & (~prev).astype(jnp.uint8)).astype(jnp.uint8)
-    counts = jax.lax.population_count(rising).astype(jnp.int32)
-    runs_ref[...] = jnp.sum(counts, axis=0)[None, :]
+    runs_ref[...] = _packed_runs(pk_ref)
+
+
+def _pad_packed(packed: Array, block_w: int) -> tuple[Array, int]:
+    """Pad packed rows to the uint8 sublane tile (zero bytes start no run)
+    and W to the lane block; returns the padded array and the real W."""
+    hp, w = packed.shape
+    pads = (-hp % _INT8_SUBLANES, -w % block_w)
+    if any(pads):
+        packed = jnp.pad(packed, ((0, pads[0]), (0, pads[1])))
+    return packed, w
 
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
 def packed_colscan(packed: Array, *, block_w: int = 128,
-                   interpret: bool = True) -> Array:
+                   interpret: bool | None = None) -> Array:
     """Step 1 on a row-packed mask. packed: (Hp, W) uint8 -> (W,) int32."""
-    hp, w = packed.shape
-    w_pad = -w % block_w
-    if w_pad:
-        packed = jnp.pad(packed, ((0, 0), (0, w_pad)))
-    wp = w + w_pad
+    packed, w = _pad_packed(packed, block_w)
+    hp, wp = packed.shape
     out = pl.pallas_call(
         _packed_colscan_kernel,
         grid=(wp // block_w,),
         in_specs=[pl.BlockSpec((hp, block_w), lambda j: (0, j))],
         out_specs=pl.BlockSpec((1, block_w), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, wp), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(packed)
     return out[0, :w]
 
 
 def _packed_fused_kernel(pk_ref, runs_ref, births_ref, deaths_ref):
     """Fused step 1 + tile-local step 2 (boundary column stitched outside)."""
-    b = pk_ref[...]
-    msb = (b >> 7).astype(jnp.uint8)
-    carry = jnp.concatenate([jnp.zeros_like(msb[:1]), msb[:-1]], axis=0)
-    prev = ((b << 1) | carry).astype(jnp.uint8)
-    rising = (b & (~prev).astype(jnp.uint8)).astype(jnp.uint8)
-    runs = jnp.sum(jax.lax.population_count(rising).astype(jnp.int32), axis=0)
-    prev_runs = jnp.concatenate([jnp.zeros((1,), jnp.int32), runs[:-1]])
-    delta = runs - prev_runs
-    runs_ref[...] = runs[None, :]
-    births_ref[...] = jnp.maximum(delta, 0)[None, :]
-    deaths_ref[...] = jnp.maximum(-delta, 0)[None, :]
+    runs = _packed_runs(pk_ref)
+    lane = jax.lax.broadcasted_iota(jnp.int32, runs.shape, 1)
+    delta = runs - jnp.where(lane == 0, 0, pltpu.roll(runs, 1, 1))
+    runs_ref[...] = runs
+    births_ref[...] = jnp.maximum(delta, 0)
+    deaths_ref[...] = jnp.maximum(-delta, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
 def packed_analyze(img: Array, *, block_w: int = 128,
-                   interpret: bool = True) -> dict[str, Array]:
+                   interpret: bool | None = None) -> dict[str, Array]:
     """Full two-step pipeline, one pass over a bit-packed image."""
-    h, w = img.shape
-    packed = pack_rows(img)
-    hp = packed.shape[0]
-    w_pad = -w % block_w
-    if w_pad:
-        packed = jnp.pad(packed, ((0, 0), (0, w_pad)))
-    wp = w + w_pad
+    packed, w = _pad_packed(pack_rows(img), block_w)
+    hp, wp = packed.shape
     spec = pl.BlockSpec((1, block_w), lambda j: (0, j))
     runs, births, deaths = pl.pallas_call(
         _packed_fused_kernel,
@@ -108,7 +117,7 @@ def packed_analyze(img: Array, *, block_w: int = 128,
         in_specs=[pl.BlockSpec((hp, block_w), lambda j: (0, j))],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((1, wp), jnp.int32)] * 3,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(packed)
     runs, births, deaths = runs[0, :w], births[0, :w], deaths[0, :w]
     # stitch tile boundaries: the kernel assumed prev=0 at each tile's first

@@ -1,38 +1,35 @@
-"""Opt-in JAX persistent compilation cache for serve/bulk entry points.
+"""JAX's persistent compilation cache, placed the same way by every entry point.
 
-A restarted fleet worker or a resumed bulk job re-lowers and re-compiles
-every rung of its bucket ladder from scratch — pure cold-start tax, since
-the shapes are identical across restarts by construction (traffic cannot
-change them, only config can). Pointing every process at one on-disk
-cache directory makes the second process's compiles disk reads.
+A restarted fleet worker, a resumed bulk job or the next smoke run would
+otherwise re-lower and re-compile every rung of its bucket ladder: pure
+cold-start tax, since the shapes are identical across restarts. Every
+entry point (``serve.py``, ``fleet.worker``, ``chip_smoke.py``, the bench
+scripts) calls :func:`enable_compile_cache` before its first compile.
 
-Deliberately opt-in (``serve.py --compile-cache DIR`` /
-``fleet.worker --compile-cache DIR``): the default CPU interpret-mode
-tests must not silently depend on cache state, and the cache directory is
-a shared mutable resource the operator should own. Thresholds are set to
-"cache everything" because the bucket ladder is a small closed set of
-executables — eviction pressure is not a concern, restart latency is.
+The directory is part of what makes an entry findable again, so it never
+moves between processes or runs:
+
+* if ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+  module sets nothing;
+* otherwise the cache is ``<checkout>/.jax_cache`` (git-ignored).
 """
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
+
 import jax
 
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+# src/repro/launch/compilecache.py -> the checkout root
+DEFAULT_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
-def enable_compile_cache(directory: str) -> bool:
-    """Point this process's JAX at a persistent compilation cache.
 
-    Returns True when the cache was enabled, False when this jax build
-    has no persistent-cache support (the caller keeps working, just
-    without restart-time compile reuse).
-    """
-    try:
-        jax.config.update("jax_compilation_cache_dir", directory)
-        # cache every executable regardless of compile time or size: the
-        # bucket ladder is a small closed set, and the whole point is that
-        # a restart pays zero recompiles
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:       # ancient jax: no persistent cache knobs
-        return False
-    return True
+def enable_compile_cache() -> str:
+    """Turn the persistent cache on; returns its directory."""
+    env = os.environ.get(ENV_VAR)
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_DIR))
+    return str(DEFAULT_DIR)

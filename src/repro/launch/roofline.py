@@ -1,11 +1,10 @@
 """Roofline accounting from the compiled dry-run artifact.
 
-TPU v5e hardware model (per chip):
-  peak bf16 compute   197 TFLOP/s
-  HBM bandwidth       819 GB/s
-  ICI                 ~50 GB/s per link (we charge ONE link — conservative;
-                      v5e has 4 usable links, so a perfect schedule could be
-                      ~4x better; stated in EXPERIMENTS.md)
+Peak rates sit in ``PEAKS``, keyed by JAX's ``device_kind``; a device that
+is not in the table is an error, never a default. The dry-run models its
+target chip, ``TARGET_DEVICE_KIND`` (TPU v5e), and charges collectives ONE
+ICI link (conservative: v5e has 4 usable links, so a perfect schedule could
+be ~4x better).
 
 Collective bytes are parsed from the *optimized* HLO of the compiled module:
 operands are not typed inline in current HLO dumps, so per-op ICI traffic is
@@ -33,22 +32,43 @@ model is cross-checked against them.
 
 from __future__ import annotations
 
+import dataclasses
 import re
 from typing import Any, Dict
 
-PEAK_FLOPS = 197e12     # bf16 / chip
-HBM_BW = 819e9          # bytes/s / chip
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peak rates."""
+
+    flops: float      # bf16 FLOP/s
+    hbm_bw: float     # HBM bytes/s
+    ici_bw: float     # bytes/s per ICI link
+
+
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s ICI over 4 links).
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    """Peak rates of ``device_kind`` (``jax.devices()[0].device_kind``)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates recorded for device kind {device_kind!r}; "
+            f"known kinds: {sorted(PEAKS)}") from None
 
 
 def cost_dict(compiled) -> Dict[str, Any]:
-    """``compiled.cost_analysis()`` compat: newer jax returns a dict, older
-    a [per-device dict] list. Single shared shim — dryrun and the tests
-    must parse the artifact identically."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):
-        cost = cost[0] if cost else {}
-    return cost
-ICI_BW = 50e9           # bytes/s / link, 1 link charged
+    """``compiled.cost_analysis()`` as a dict ({} when XLA reports none);
+    dryrun and the tests parse the artifact through this one function."""
+    return compiled.cost_analysis() or {}
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -143,13 +163,14 @@ def terms(
 ) -> Dict[str, float]:
     chips = n_partitions
     cg = coll_bytes_per_partition * n_partitions
+    peak = peaks(TARGET_DEVICE_KIND)
     return {
         "flops_global": flops_global,
         "bytes_global": bytes_global,
         "coll_bytes_global": cg,
-        "compute_s": flops_global / (chips * PEAK_FLOPS),
-        "memory_s": bytes_global / (chips * HBM_BW),
-        "collective_s": cg / (chips * ICI_BW),
+        "compute_s": flops_global / (chips * peak.flops),
+        "memory_s": bytes_global / (chips * peak.hbm_bw),
+        "collective_s": cg / (chips * peak.ici_bw),
     }
 
 

@@ -1,28 +1,33 @@
-"""CLI serve driver: --arch <id> --smoke serves batched requests; or
---workload ychg runs the paper's image-analysis service on mask batches,
-in-process or over the network front end.
+"""CLI serve driver: the paper's image-analysis service on mask batches
+(the default ``--workload ychg``), in-process or over the network front
+end; ``--workload lm --arch <id> --smoke`` serves a language model.
+
+Every mode first turns on the persistent compilation cache
+(``launch.compilecache``: ``$JAX_COMPILATION_CACHE_DIR``, else
+``<checkout>/.jax_cache``).
 
 Usage:
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke
-  PYTHONPATH=src python -m repro.launch.serve --workload ychg --res 2048
+  PYTHONPATH=src python -m repro.launch.serve --res 2048
+  PYTHONPATH=src python -m repro.launch.serve --workload lm \\
+      --arch qwen2-0.5b --smoke
   # network modes (repro.frontend):
-  PYTHONPATH=src python -m repro.launch.serve --workload ychg \\
+  PYTHONPATH=src python -m repro.launch.serve \\
       --listen 127.0.0.1:8788                  # serve over loopback HTTP
-  PYTHONPATH=src python -m repro.launch.serve --workload ychg \\
+  PYTHONPATH=src python -m repro.launch.serve \\
       --connect http://127.0.0.1:8788          # drive a remote server
-  PYTHONPATH=src python -m repro.launch.serve --workload ychg \\
+  PYTHONPATH=src python -m repro.launch.serve \\
       --res 64 --batch 4 --frontend-smoke      # CI end-to-end assert
-  PYTHONPATH=src python -m repro.launch.serve --workload ychg \\
+  PYTHONPATH=src python -m repro.launch.serve \\
       --fleet 4 --listen 127.0.0.1:8788        # router over 4 workers
-  PYTHONPATH=src python -m repro.launch.serve --workload ychg \\
+  PYTHONPATH=src python -m repro.launch.serve \\
       --res 64 --batch 4 --fleet-smoke         # CI fleet assert
   # granule-scale bulk analysis (repro.scene):
-  PYTHONPATH=src python -m repro.launch.serve --workload ychg scene \\
+  PYTHONPATH=src python -m repro.launch.serve scene \\
       --granules 4 --scene-height 4096 --scene-width 2048 \\
       --out results/ --ckpt ckpt/               # resumable bulk job
-  PYTHONPATH=src python -m repro.launch.serve --workload ychg \\
+  PYTHONPATH=src python -m repro.launch.serve \\
       --scene-smoke                             # CI scene assert
-  PYTHONPATH=src python -m repro.launch.serve --workload ychg \\
+  PYTHONPATH=src python -m repro.launch.serve \\
       --res 64 --batch 4 --slo-smoke            # CI traffic-class assert
 """
 
@@ -32,17 +37,20 @@ import argparse
 import threading
 import time
 
-import jax
 import numpy as np
 
 from repro import obs
-from repro.configs.archs import smoke_config
-from repro.configs import get_config
-from repro.models import count_params, init_params
-from repro.serve import ServeEngine
+from repro.launch.compilecache import enable_compile_cache
 
 
 def serve_lm(args):
+    import jax
+
+    from repro.configs import get_config
+    from repro.configs.archs import smoke_config
+    from repro.models import count_params, init_params
+    from repro.serve import ServeEngine
+
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     print(f"{cfg.name}: {count_params(cfg) / 1e6:.2f}M params")
     params = init_params(cfg, jax.random.PRNGKey(0))
@@ -656,8 +664,6 @@ def _worker_args(args):
         wa += ["--max-queue-depth", str(args.max_queue_depth)]
     if args.bucket_queue_depth is not None:
         wa += ["--bucket-queue-depth", str(args.bucket_queue_depth)]
-    if args.compile_cache:
-        wa += ["--compile-cache", args.compile_cache]
     if args.trace_dump:
         wa += ["--trace-dump", args.trace_dump]
     return wa
@@ -707,8 +713,9 @@ def fleet_smoke(args):
     workers on loopback (ephemeral ports everywhere).
 
       1. **bit-identity** — a streamed batch through router -> worker RPC
-         is byte-identical (values, dtypes, shapes) to in-process
-         ``YCHGService.submit`` on the same masks;
+         is byte-identical (values, dtypes, shapes) to the paper's NumPy
+         baseline ``core.serial.analyze_numpy`` on the same masks (this
+         process never touches JAX's backend: the workers own the chips);
       2. **rerouting** — hard-kill the worker owning one mask's keyspace;
          the repeat analyze fails over to the survivor, still matches,
          and ``ychg_fleet_rerouted_total`` moves;
@@ -722,8 +729,8 @@ def fleet_smoke(args):
     """
     import asyncio
 
+    from repro.core import serial
     from repro.data import modis
-    from repro.engine import Engine
     from repro.fleet import (
         FleetRouter,
         FleetSupervisor,
@@ -732,7 +739,6 @@ def fleet_smoke(args):
     )
     from repro.fleet.router import routing_key
     from repro.frontend import YCHGClient
-    from repro.service import YCHGService
 
     def counter(text, name):
         for line in text.splitlines():
@@ -749,8 +755,7 @@ def fleet_smoke(args):
                                  f"not bit-identical through the router")
 
     masks = [modis.snowfield(args.res, seed=s) for s in range(args.batch)]
-    with YCHGService(Engine(), _service_config(args)) as svc:
-        want = [svc.submit(m).result(timeout=600).to_host() for m in masks]
+    want = [serial.analyze_numpy(m) for m in masks]
 
     sup = FleetSupervisor(2, worker_args=_worker_args(args))
     try:
@@ -771,7 +776,8 @@ def fleet_smoke(args):
                         f"the router: {item and item.error}")
                 check_identical("identity", item.result, want_res)
             print(f"fleet smoke: {len(masks)} masks through router over 2 "
-                  f"workers bit-identical to in-process submit", flush=True)
+                  f"workers bit-identical to the NumPy reference",
+                  flush=True)
 
             # trace leg: one traced batch, then merge the client-local,
             # router, and per-worker flight recorders and assert a single
@@ -1065,12 +1071,12 @@ def scene_smoke(args):
               f"result, scene gauges on /metrics", flush=True)
 
 
-def main():
-    ap = argparse.ArgumentParser()
+def build_parser() -> argparse.ArgumentParser:
+    ap = argparse.ArgumentParser(prog="repro.launch.serve")
     ap.add_argument("command", nargs="?", choices=["scene"],
                     help="optional subcommand: 'scene' runs a resumable "
                          "granule bulk job (repro.scene)")
-    ap.add_argument("--workload", default="lm", choices=["lm", "ychg"])
+    ap.add_argument("--workload", default="ychg", choices=["ychg", "lm"])
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--batch", type=int, default=4)
@@ -1112,11 +1118,6 @@ def main():
     ap.add_argument("--bucket-queue-depth", type=int, default=None)
     ap.add_argument("--policy", default="block", choices=["block", "shed"],
                     help="overload policy for --listen/--frontend-smoke")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="enable JAX's persistent compilation cache in DIR "
-                         "(restarted workers / resumed bulk jobs reload "
-                         "their compiles from disk); plumbed to --fleet "
-                         "workers")
     ap.add_argument("--trace-dump", default=None, metavar="PATH",
                     help="dump the flight recorder (recent request traces) "
                          "as Chrome-trace JSON to PATH on shutdown; "
@@ -1151,17 +1152,14 @@ def main():
                      help="stacks between mid-granule checkpoints")
     scn.add_argument("--max-stacks", type=int, default=None,
                      help="stop (with a checkpoint) after N stacks")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
     if args.trace_dump:
         obs.configure(dump_path=args.trace_dump)
-    if args.compile_cache:
-        from repro.launch.compilecache import enable_compile_cache
-
-        if enable_compile_cache(args.compile_cache):
-            print(f"compile cache: {args.compile_cache}", flush=True)
-        else:
-            print("compile cache: unsupported by this jax build, "
-                  "continuing without", flush=True)
+    print(f"compile cache: {enable_compile_cache()}", flush=True)
     def smoke(tag, fn):
         """Run a CI smoke leg; on ANY failure dump the flight recorder
         first (with --trace-dump, CI uploads it as a debugging artifact)

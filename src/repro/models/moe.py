@@ -149,7 +149,6 @@ def _local_dispatch(xt, gates, idx, e, cap, d):
 def moe_apply_alltoall(p: dict, x: Array, cfg, shd: Sharder) -> Tuple[Array, Array]:
     """x: (B,S,d) -> (y, aux). Requires shd.mesh with a "model" axis."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = shd.mesh
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -223,13 +222,13 @@ def moe_apply_alltoall(p: dict, x: Array, cfg, shd: Sharder) -> Tuple[Array, Arr
             aux = jax.lax.pmean(aux, ax)
         return y.reshape(x_blk.shape), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(batch_axes or None, None, None), P(None, None),
                   wg_spec, wg_spec, wd_spec),
         out_specs=(P(batch_axes or None, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if "shared" in p:

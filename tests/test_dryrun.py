@@ -90,7 +90,10 @@ def test_extrapolation():
 def test_roofline_terms_and_dominant():
     t = roofline.terms(flops_global=1e15, bytes_global=1e12,
                        coll_bytes_per_partition=1e9, n_partitions=256)
-    assert t["compute_s"] == pytest.approx(1e15 / (256 * roofline.PEAK_FLOPS))
+    peak = roofline.peaks(roofline.TARGET_DEVICE_KIND)
+    assert t["compute_s"] == pytest.approx(1e15 / (256 * peak.flops))
+    with pytest.raises(ValueError, match="no peak rates"):
+        roofline.peaks("cpu")
     assert roofline.dominant(t) in ("compute_s", "memory_s", "collective_s")
 
 

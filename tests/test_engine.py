@@ -231,6 +231,17 @@ def test_config_stream_vmem_budget_routes_to_streamed():
                          ychg.analyze(jnp.asarray(imgs)))
 
 
+def test_interpret_true_is_refused_on_a_tpu(monkeypatch):
+    """On a TPU the kernels compile; asking the engine to interpret them
+    there is refused at construction, while None (auto) is accepted."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        Engine(YCHGConfig(interpret=True))
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        Engine().with_config(interpret=True)
+    assert Engine(YCHGConfig(interpret=None)).config.interpret is None
+
+
 def test_config_dtype_casts_on_ingest():
     img = np.array([[0, 2], [3, 0]], np.int64)
     res = Engine(YCHGConfig(dtype="uint8")).analyze(img)

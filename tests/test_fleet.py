@@ -505,3 +505,45 @@ def test_rollup_sums_worker_histograms_exactly():
         assert page.get("ychg_completed_total") == n_requests
     finally:
         _close_fleet(closers)
+
+
+# ------------------------------------------------------------- supervision
+
+
+def test_supervisor_refuses_more_workers_than_tpu_chips(monkeypatch):
+    """A chip serves one process: on a host with 2 TPU chips a fleet of 3
+    is refused before anything spawns, and a fleet of 2 pins slot i to
+    chip i (a restart reclaims the same chip)."""
+    import repro.fleet.router as router_mod
+    from repro.fleet import FleetSupervisor
+
+    monkeypatch.setattr(router_mod, "host_tpu_chips", lambda: 2)
+    with pytest.raises(ValueError, match="at most 2 workers"):
+        FleetSupervisor(3)
+    assert [l.chip for l in FleetSupervisor(2).links] == [0, 1]
+    monkeypatch.setattr(router_mod, "host_tpu_chips", lambda: 0)
+    assert [l.chip for l in FleetSupervisor(3).links] == [None] * 3
+
+
+def test_pinned_env_shows_each_worker_one_chip():
+    from repro.fleet.chips import pinned_env
+
+    a, b = pinned_env(0), pinned_env(3)
+    assert (a["TPU_VISIBLE_CHIPS"], b["TPU_VISIBLE_CHIPS"]) == ("0", "3")
+    assert a["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert a["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert a["TPU_PROCESS_PORT"] != b["TPU_PROCESS_PORT"]
+    # pinned processes run side by side without lifting libtpu's lock
+    assert "ALLOW_MULTIPLE_LIBTPU_LOAD" not in a
+
+
+def test_worker_that_dies_before_ready_says_why():
+    """The supervisor keeps a worker's stderr: a worker that exits before
+    its READY handshake surfaces its own last words in the error."""
+    from repro.fleet import FleetSupervisor
+
+    sup = FleetSupervisor(1, worker_args=["--no-such-flag"],
+                          start_timeout_s=60.0)
+    with pytest.raises(RuntimeError,
+                       match=r"(?s)READY handshake.*--no-such-flag"):
+        sup.start()

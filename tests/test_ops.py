@@ -5,8 +5,8 @@ Covers the (op, platform) registry and the two new operators end to end:
     registered ops, never a bare ``KeyError``;
   * registration is live — a backend registered for an op after an engine
     was built wins the very next resolution (generation bump);
-  * platform fallback — an op whose backends claim no current platform
-    resolves to its best batch-capable backend with a ``RuntimeWarning``;
+  * no platform fallback — an op whose backends claim no current platform
+    raises a ``ValueError`` naming the platform; nothing is substituted;
   * ccl / denoise parity — jnp reference vs Pallas kernel bit-identical
     on ragged corpora, ccl vs a pure-Python BFS oracle, and both ops
     pad-invariant (zero padding never changes the native region);
@@ -41,6 +41,7 @@ from repro.engine.ops import (
 )
 from repro.kernels import ccl as cclmod
 from repro.kernels import denoise as dnmod
+from repro.kernels.platform import VmemBudgetError
 from repro.service import Service, ServiceConfig
 from repro.service.cache import make_key
 
@@ -96,18 +97,22 @@ def test_register_backend_for_op_is_live_immediately():
     assert eng.resolve_backend(op="ccl") == "jax"
 
 
-def test_op_with_no_backend_for_platform_warns_and_falls_back():
-    """An op registered only for some other platform resolves with a
-    clear RuntimeWarning — never a KeyError."""
+def test_op_with_no_backend_for_platform_raises():
+    """An op registered only for some other platform refuses to resolve
+    on this one: a ValueError naming the platform, never a KeyError and
+    never another platform's backend."""
     registry.register_backend(registry.BackendSpec(
         name="_test_tpu_only", op="_toyop",
         run=lambda x, c: cclmod.labels(x), supports_batch=True,
         supports_mesh=False, device_kinds=("tpu",), priority={"tpu": 10},
     ))
     try:
-        with pytest.warns(RuntimeWarning, match="falling back to backend"):
-            spec = resolve("auto", platform="cpu", op="_toyop")
-        assert spec.name == "_test_tpu_only"
+        with pytest.raises(ValueError, match="platform 'cpu'"):
+            resolve("auto", platform="cpu", op="_toyop")
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            assert resolve("auto", platform="tpu",
+                           op="_toyop").name == "_test_tpu_only"
     finally:
         registry.unregister_backend("_test_tpu_only", op="_toyop")
     with pytest.raises(UnknownOpError):
@@ -184,6 +189,20 @@ def test_denoise_pallas_bit_identical_to_reference():
     b = dnmod.denoise_pallas(jnp.asarray(stack))
     np.testing.assert_array_equal(np.asarray(a.image), np.asarray(b.image))
     assert np.asarray(a.image).dtype == np.float32
+
+
+@pytest.mark.parametrize("kernel, side", [(cclmod.labels_pallas, 4096),
+                                          (dnmod.denoise_pallas, 2048)])
+def test_oversize_whole_image_block_raises_named_error(kernel, side):
+    """A whole-image block no VMEM limit can hold is refused at trace
+    time with an error naming the shape and the limit; no other backend
+    stands in, and nothing is allocated."""
+    big = jax.ShapeDtypeStruct((2, side, side), jnp.uint8)
+    with pytest.raises(VmemBudgetError,
+                       match=rf"\(1, {side}, {side}\).*100 MiB limit"):
+        jax.eval_shape(kernel, big)
+    # one rung below the ladder's top still fits
+    jax.eval_shape(kernel, jax.ShapeDtypeStruct((2, 1024, 1024), jnp.uint8))
 
 
 def test_denoise_is_pad_invariant():

@@ -1,0 +1,65 @@
+"""The main path's Pallas kernels compile for a TPU v5e, at real sizes.
+
+Interpret mode (every other kernel test) proves the kernels exact but
+nothing about Mosaic: unaligned blocks, i1 <-> i8 casts and scoped-VMEM
+overflows only show when the chip's compiler sees the kernel. These tests
+compile against a *described* ``v5e:2x2`` topology, so they need the TPU
+compiler (libtpu) but no chip, and run nothing.
+
+The topology is described inside a module fixture, never at import time:
+only the pytest worker that runs this file loads libtpu, and where it
+cannot be loaded the tests skip from the fixture. The persistent
+compilation cache is off around the compiles: an entry written for a
+described chip cannot be read back without one.
+"""
+
+import functools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.kernels import ccl, denoise, ychg_fused
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or it is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+CASES = {
+    # the fused yCHG kernel at a full serving batch of the top ladder rung
+    "fused_full_column": (ychg_fused.fused_analyze_pallas, (8, 1024, 1024)),
+    # the H-streamed variant on a MODIS L1B 250 m granule
+    "fused_streamed": (ychg_fused.fused_analyze_streamed, (1, 8120, 5416)),
+    # the whole-image kernels at the same serving batch
+    "ccl": (ccl.labels_pallas, (8, 1024, 1024)),
+    "denoise": (denoise.denoise_pallas, (8, 1024, 1024)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, case):
+    fn, shape = CASES[case]
+    x = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=one_chip)
+    compiled = jax.jit(functools.partial(fn, interpret=False)).lower(x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
