@@ -20,6 +20,11 @@ Every verb returns the op's result pytree (``YCHGResult`` for yCHG — see
 arrays that can cross ``jit``/``shard_map`` boundaries and never leave the
 device implicitly. ``.to_host()`` produces the legacy host dict.
 
+Tracing: a host input's transfer is an ``engine.put`` span, each backend
+run an ``engine.dispatch`` span, and every ``to_host()`` an
+``engine.fetch`` span. They join the trace the calling tier made current
+(``repro.obs.use_trace``), or each opens and finishes a trace of its own.
+
 ``YCHGEngine`` remains as a deprecation shim over ``Engine`` (same policy,
 op pinned to ``"ychg"``), mirroring the PR 2 treatment of
 ``core.api.analyze_image``.
@@ -40,6 +45,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.ychg import YCHGSummary
 from repro.engine import registry
+from repro.obs import join_trace
 
 Array = jax.Array
 
@@ -114,13 +120,22 @@ class YCHGResult:
 
     def to_host(self) -> Dict[str, np.ndarray]:
         """The legacy ``core.api.analyze_image`` dict: host NumPy values."""
-        s = self.to_summary()
-        return {f: np.asarray(getattr(s, f)) for f in _FIELDS}
+        return fetch(self, _FIELDS)
 
 
 jax.tree_util.register_dataclass(
     YCHGResult, data_fields=list(_FIELDS), meta_fields=["batched"]
 )
+
+
+def fetch(result: Any, fields: Sequence[str]) -> Dict[str, np.ndarray]:
+    """Every op result's ``to_host()``: the wait for the device, the
+    squeeze of a B=1 view and the device->host copy, under one
+    ``engine.fetch`` span (meta: the result's device bytes)."""
+    with join_trace("engine") as tr, tr.span("engine.fetch", bytes=sum(
+            getattr(result, f).nbytes for f in fields)):
+        s = result.to_summary()
+        return {f: np.asarray(getattr(s, f)) for f in fields}
 
 
 def _from_summary(s: YCHGSummary, batched: bool) -> YCHGResult:
@@ -223,11 +238,22 @@ class Engine:
 
         return engine_ops.get_op(op)
 
-    def _ingest(self, imgs: Any) -> Array:
+    def _ingest(self, imgs: Any, *, single: bool = False) -> Array:
         # device arrays pass through untouched: no host round-trip, and no
         # jnp.asarray no-op either (it costs ~17us/call of pure dispatch —
-        # the engine's <=5us/call overhead budget lives or dies here)
-        x = imgs if isinstance(imgs, jax.Array) else jnp.asarray(imgs)
+        # the engine's <=5us/call overhead budget lives or dies here).
+        # ``single``: a lone (H, W) mask, given its batch axis here
+        if isinstance(imgs, jax.Array):
+            x = imgs[None] if single else imgs
+        else:
+            host = np.asarray(imgs)
+            # the runtime's relayout and the copy it starts, and the eager
+            # reshape that makes a lone mask the B=1 stack the backend takes
+            with join_trace("engine") as tr, tr.span("engine.put",
+                                                     bytes=host.nbytes):
+                x = jnp.asarray(host)
+                if single:
+                    x = x[None]
         if self._cast_dtype is not None and x.dtype != self._cast_dtype:
             x = x.astype(self._cast_dtype)
         return x
@@ -236,11 +262,11 @@ class Engine:
 
     def analyze(self, img: Any, *, op: Optional[str] = None):
         """One (H, W) mask -> B=1 result (never copies device->host)."""
-        x = self._ingest(img)
-        if x.ndim != 2:
-            raise ValueError(f"analyze expects an (H, W) mask, got {x.shape}; "
-                             "use analyze_batch for stacks")
-        return self._run(x[None], batched=False, op=op or self.op)
+        if np.ndim(img) != 2:
+            raise ValueError(f"analyze expects an (H, W) mask, got "
+                             f"{np.shape(img)}; use analyze_batch for stacks")
+        x = self._ingest(img, single=True)
+        return self._run(x, batched=False, op=op or self.op)
 
     def analyze_batch(self, stack: Any, *, op: Optional[str] = None):
         """A (B, H, W) stack in one device computation."""
@@ -340,13 +366,20 @@ class Engine:
         # counted BEFORE the run so a raising backend still shows up in
         # call_count; the dispatch-cost histogram only sees successes
         registry.note_call(spec.name, op)
-        t0 = time.monotonic()
-        if self.mesh is not None:
-            out = opspec.from_summary(
-                self._run_meshed(spec, opspec, imgs), batched)
-        else:
-            out = opspec.from_summary(spec.run(imgs, self.config), batched)
-        registry.note_dispatch(spec.name, time.monotonic() - t0, op)
+        with join_trace("engine") as tr, tr.span(
+                "engine.dispatch", backend=spec.name, op=op,
+                px=imgs.size) as sp:
+            t0 = time.monotonic()
+            if self.mesh is not None:
+                out = opspec.from_summary(
+                    self._run_meshed(spec, opspec, imgs), batched)
+            else:
+                out = opspec.from_summary(spec.run(imgs, self.config),
+                                          batched)
+            t1 = time.monotonic()
+            # the span and the dispatch histogram share these two reads
+            sp.stamp(t0, t1)
+        registry.note_dispatch(spec.name, t1 - t0, op)
         return out
 
     def _run_meshed(self, spec: registry.BackendSpec, opspec,

@@ -73,8 +73,9 @@ class CCLResult:
         return _ccl.CCLSummary(self.labels[0], self.n_components[0])
 
     def to_host(self) -> Dict[str, np.ndarray]:
-        s = self.to_summary()
-        return {f: np.asarray(getattr(s, f)) for f in _ccl.CCL_FIELDS}
+        from repro.engine.engine import fetch
+
+        return fetch(self, _ccl.CCL_FIELDS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,9 +100,9 @@ class DenoiseResult:
         return _denoise.DenoiseSummary(self.image[0])
 
     def to_host(self) -> Dict[str, np.ndarray]:
-        s = self.to_summary()
-        return {f: np.asarray(getattr(s, f))
-                for f in _denoise.DENOISE_FIELDS}
+        from repro.engine.engine import fetch
+
+        return fetch(self, _denoise.DENOISE_FIELDS)
 
 
 jax.tree_util.register_dataclass(

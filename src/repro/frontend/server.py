@@ -60,7 +60,7 @@ import numpy as np
 from repro.engine import registry
 from repro.engine.ops import op_names
 from repro.frontend import protocol
-from repro.obs import PromBuilder, maybe_trace, recorder
+from repro.obs import PromBuilder, maybe_trace, recorder, use_trace
 from repro.service import ServiceOverloaded, YCHGService
 from repro.service.metrics import bucket_labels
 
@@ -334,12 +334,13 @@ class FrontendServer:
                     writer, 429, out, keep,
                     extra=[("Retry-After", str(max(1, math.ceil(retry))))])
                 return
-            await _respond_json(
-                writer, 200,
-                {"id": payload.get("id"),
-                 "result": protocol.encode_result(
-                     result, op or self.service.engine.op)},
-                keep)
+            # the result's engine.fetch span joins this request's trace
+            with use_trace(tr):
+                out = protocol.encode_result(result,
+                                             op or self.service.engine.op)
+            await _respond_json(writer, 200,
+                                {"id": payload.get("id"), "result": out},
+                                keep)
         finally:
             # the frontend created this trace (possibly adopting the
             # client's id), so the frontend finishes it — on every path
@@ -373,11 +374,11 @@ class FrontendServer:
                     writer, 429, out, keep,
                     extra=[("Retry-After", str(max(1, math.ceil(retry))))])
                 return
-            await _respond_json(
-                writer, 200,
-                {"id": payload.get("id"),
-                 "result": protocol.encode_result(result, stages[-1])},
-                keep)
+            with use_trace(tr):
+                out = protocol.encode_result(result, stages[-1])
+            await _respond_json(writer, 200,
+                                {"id": payload.get("id"), "result": out},
+                                keep)
         finally:
             tr.finish()
 
@@ -409,7 +410,8 @@ class FrontendServer:
                 return {"id": rid, "error": str(e), "status": 400}
             except Exception as e:   # a failed request must not kill the stream
                 return {"id": rid, "error": str(e), "status": 500}
-            return {"id": rid, "result": protocol.encode_result(result)}
+            with use_trace(tr):
+                return {"id": rid, "result": protocol.encode_result(result)}
 
         writer.write(_head(200, "application/x-ndjson", keep=False,
                            chunked=True))
@@ -618,8 +620,9 @@ class FrontendServer:
                 return
             finally:
                 tr.finish()
-            await send({"id": rid,
-                        "result": protocol.encode_result(result, wire_op)})
+            with use_trace(tr):
+                out = protocol.encode_result(result, wire_op)
+            await send({"id": rid, "result": out})
 
         try:
             while True:

@@ -172,6 +172,7 @@ def labels_pallas(stack: Array, *, interpret: bool | None = None) -> CCLSummary:
         out_specs=pl.BlockSpec((1, h, w), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, w), jnp.int32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit),
+        name="ccl_labels",
         interpret=resolve_interpret(interpret),
     )(stack)
     return _canonicalize(raw, stack != 0)

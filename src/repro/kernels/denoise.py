@@ -110,6 +110,7 @@ def denoise_pallas(stack: Array, *,
         out_specs=pl.BlockSpec((1, h, w), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, w), jnp.float32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=limit),
+        name="denoise_filter",
         interpret=resolve_interpret(interpret),
     )(stack)
     return DenoiseSummary(image=out)

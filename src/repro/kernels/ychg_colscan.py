@@ -96,6 +96,7 @@ def colscan_runs_pallas(img: Array, *, block_w: int = 128,
         in_specs=[pl.BlockSpec((hp, block_w), lambda j: (0, j))],
         out_specs=pl.BlockSpec((1, block_w), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, wp), jnp.int32),
+        name="ychg_colscan",
         interpret=resolve_interpret(interpret),
     )(x)
     return out[0, :w]
@@ -121,6 +122,7 @@ def colscan_runs_streamed(
         out_specs=pl.BlockSpec((1, block_w), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, wp), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.int32)],
+        name="ychg_colscan_streamed",
         interpret=resolve_interpret(interpret),
     )(x)
     return out[0, :w]
@@ -145,6 +147,7 @@ def transitions_pallas(
         in_specs=[spec, spec],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((1, wp), jnp.int32)] * 3,
+        name="ychg_transitions",
         interpret=resolve_interpret(interpret),
     )(runs[None, :], prev[None, :])
     return (trans[0, :w] != 0), births[0, :w], deaths[0, :w]

@@ -182,6 +182,7 @@ def fused_analyze_pallas(
         out_specs=[vec, vec, vec, vec, tot, tot],
         out_shape=_out_shapes(b, wp),
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.int32)],
+        name="ychg_fused_full",
         interpret=resolve_interpret(interpret),
     )(x)
     return _unpack(outs, w)
@@ -270,6 +271,7 @@ def fused_analyze_streamed(
         out_specs=[vec, vec, vec, vec, tot, tot],
         out_shape=_out_shapes(b, wp),
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.int32)] * 2,
+        name="ychg_fused_streamed",
         interpret=resolve_interpret(interpret),
     )(x)
     return _unpack(outs, w)

@@ -89,6 +89,7 @@ def packed_colscan(packed: Array, *, block_w: int = 128,
         in_specs=[pl.BlockSpec((hp, block_w), lambda j: (0, j))],
         out_specs=pl.BlockSpec((1, block_w), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, wp), jnp.int32),
+        name="ychg_packed_colscan",
         interpret=resolve_interpret(interpret),
     )(packed)
     return out[0, :w]
@@ -117,6 +118,7 @@ def packed_analyze(img: Array, *, block_w: int = 128,
         in_specs=[pl.BlockSpec((hp, block_w), lambda j: (0, j))],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((1, wp), jnp.int32)] * 3,
+        name="ychg_packed_fused",
         interpret=resolve_interpret(interpret),
     )(packed)
     runs, births, deaths = runs[0, :w], births[0, :w], deaths[0, :w]

@@ -30,11 +30,13 @@ from repro.obs.trace import (
     Trace,
     auto_dump,
     configure,
+    join_trace,
     maybe_trace,
     mono_to_wall_us,
     new_trace_id,
     recorder,
     tracing_enabled,
+    use_trace,
 )
 
 __all__ = [
@@ -58,9 +60,11 @@ __all__ = [
     "Trace",
     "auto_dump",
     "configure",
+    "join_trace",
     "maybe_trace",
     "mono_to_wall_us",
     "new_trace_id",
     "recorder",
     "tracing_enabled",
+    "use_trace",
 ]

@@ -10,12 +10,20 @@ Design constraints, in priority order:
    ``time.monotonic()`` (immune to NTP steps). Each process records one
    (wall, mono) epoch pair at import; export converts mono timestamps to
    the wall axis so spans from router + workers line up on one Perfetto
-   timeline to within clock-sync error.
+   timeline to within clock-sync error. A live span entered with
+   ``with`` is also a ``jax.profiler.TraceAnnotation`` of the same name,
+   so the stage shows on the host plane of a profiler trace, on the
+   device ops' clock. The annotation is looked up only once the process
+   has imported jax; this module never imports it.
 3. **Creator finishes.** The tier that *creates* a Trace (frontend
    handler, router request, scene granule, or ``YCHGService.submit`` when
    called without one) calls ``finish()``; everyone handed an existing
    trace only adds spans. ``finish`` is idempotent, so belt-and-braces
    finishing in error paths is safe.
+
+A tier that owns a trace makes it *current* (``use_trace``) around the
+code it calls; the engine's spans join the current trace, or each opens
+a trace of its own (``join_trace``) where no tier made one current.
 
 The flight recorder keeps the most recent N *completed* traces in a ring
 and serialises them as Chrome-trace JSON (the ``traceEvents`` array form)
@@ -26,8 +34,10 @@ dispatch-crash auto-dump.
 from __future__ import annotations
 
 import collections
+import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -75,11 +85,23 @@ def new_trace_id() -> str:
     return os.urandom(8).hex()
 
 
-class Span:
-    """One named interval inside a trace. Use as a context manager or via
-    Trace.add() with explicit timestamps."""
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is imported
 
-    __slots__ = ("name", "t0", "t1", "meta", "_trace")
+
+def _annotation_type():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        prof = sys.modules.get("jax.profiler")
+        _ANNOTATION = getattr(prof, "TraceAnnotation", None)
+    return _ANNOTATION
+
+
+class Span:
+    """One named interval inside a trace. Use as a context manager (which
+    also opens a profiler annotation of the same name) or via Trace.add()
+    with explicit timestamps."""
+
+    __slots__ = ("name", "t0", "t1", "meta", "_trace", "_ann", "_stamped")
 
     def __init__(self, trace: "Trace", name: str, **meta):
         self._trace = trace
@@ -87,13 +109,28 @@ class Span:
         self.t0 = 0.0
         self.t1 = 0.0
         self.meta = meta
+        self._ann = None
+        self._stamped = False
 
     def __enter__(self) -> "Span":
+        ann = _annotation_type()
+        if ann is not None:
+            # the name alone: a cheap native check when no profiler runs
+            self._ann = ann(self.name)
+            self._ann.__enter__()
         self.t0 = time.monotonic()
         return self
 
+    def stamp(self, t0: float, t1: float) -> None:
+        """Record the interval the caller timed itself inside the span, so
+        the span and the caller's own measure share one pair of reads."""
+        self.t0, self.t1, self._stamped = t0, t1, True
+
     def __exit__(self, *exc) -> None:
-        self.t1 = time.monotonic()
+        if not self._stamped:
+            self.t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         self._trace._record(self)
         return None
 
@@ -175,12 +212,65 @@ class _NullSpan:
     def __enter__(self):
         return self
 
+    def stamp(self, t0: float, t1: float) -> None:
+        return None
+
     def __exit__(self, *exc):
         return None
 
 
 NULL_TRACE = _NullTrace()
 _NULL_SPAN = _NullSpan()
+
+# The trace the calling tier made current; None where no tier made one.
+_CURRENT: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_obs_current_trace", default=None)
+
+
+class use_trace:
+    """``with use_trace(tr):`` makes ``tr`` the current trace of the code
+    called inside (a thread's or task's own context), so the engine's
+    spans join it. ``NULL_TRACE`` makes the engine record nothing."""
+
+    __slots__ = ("_trace", "_token")
+
+    def __init__(self, trace):
+        self._trace = trace
+        self._token = None
+
+    def __enter__(self):
+        self._token = _CURRENT.set(self._trace)
+        return self._trace
+
+    def __exit__(self, *exc) -> None:
+        _CURRENT.reset(self._token)
+        return None
+
+
+class join_trace:
+    """``with join_trace(process) as tr:`` gives the current trace, else a
+    trace of the block's own, finished when the block ends
+    (``NULL_TRACE`` when tracing is off)."""
+
+    __slots__ = ("_process", "_own")
+
+    def __init__(self, process: str):
+        self._process = process
+        self._own = None
+
+    def __enter__(self):
+        tr = _CURRENT.get()
+        if tr is not None:
+            return tr
+        if not _STATE.enabled:
+            return NULL_TRACE
+        self._own = Trace(process=self._process)
+        return self._own
+
+    def __exit__(self, *exc) -> None:
+        if self._own is not None:
+            self._own.finish()
+        return None
 
 
 def maybe_trace(trace_id: Optional[str] = None,

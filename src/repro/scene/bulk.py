@@ -35,7 +35,7 @@ import numpy as np
 
 from repro.checkpoint import Checkpointer
 from repro.engine import Engine
-from repro.obs import NULL_TRACE, maybe_trace
+from repro.obs import NULL_TRACE, maybe_trace, use_trace
 from repro.scene.granule import GranuleReader, GranuleSpec
 from repro.scene.result import write_scene_result
 from repro.scene.runner import (
@@ -174,10 +174,8 @@ class BulkJob:
         tr = NULL_TRACE  # current granule's trace (one trace per granule)
 
         def save_ckpt(gi: int, st: SceneState) -> None:
-            c0 = time.monotonic()
-            self._save(gi, st, resumes)
-            tr.add("scene.checkpoint", c0, time.monotonic(),
-                   granule=gi, tile=st.next_tile)
+            with tr.span("scene.checkpoint", granule=gi, tile=st.next_tile):
+                self._save(gi, st, resumes)
 
         def interrupted(gi: int, st: SceneState) -> BulkJobReport:
             save_ckpt(gi, st)
@@ -201,19 +199,18 @@ class BulkJob:
                 if max_stacks is not None and stacks_done >= max_stacks:
                     return interrupted(gi, state)
                 n = min(cfg.stack_tiles, reader.n_tiles - state.next_tile)
-                r0 = time.monotonic()
-                stack = reader.read_stack(state.next_tile, n)
-                r1 = time.monotonic()
-                tr.add("scene.read", r0, r1, granule=spec.granule_id,
-                       tile=state.next_tile, tiles=n)
-                res = self.runner.engine.analyze_batch(stack)
-                runs = np.asarray(res.runs)
-                c1 = time.monotonic()
-                tr.add("scene.compute", r1, c1, granule=spec.granule_id,
-                       tiles=n)
-                self.runner.update(state, stack, runs)
-                tr.add("scene.stitch", c1, time.monotonic(),
-                       granule=spec.granule_id)
+                with tr.span("scene.read", granule=spec.granule_id,
+                             tile=state.next_tile, tiles=n):
+                    stack = reader.read_stack(state.next_tile, n)
+                # engine.put and engine.dispatch join the granule's trace
+                with use_trace(tr), tr.span(
+                        "scene.compute", granule=spec.granule_id, tiles=n):
+                    res = self.runner.engine.analyze_batch(stack)
+                    # the per-stack wait for the device
+                    with tr.span("scene.sync", bytes=res.runs.nbytes):
+                        runs = np.asarray(res.runs)
+                with tr.span("scene.stitch", granule=spec.granule_id):
+                    self.runner.update(state, stack, runs)
                 stacks_done += 1
                 tiles_done += n
                 since_ckpt += 1
@@ -222,11 +219,10 @@ class BulkJob:
                 if since_ckpt >= cfg.checkpoint_every:
                     save_ckpt(gi, state)
                     since_ckpt = 0
-            w0 = time.monotonic()
-            result = self.runner.finalize(reader, state, self.progress)
-            written.append(write_scene_result(self.output_path(spec), result))
-            tr.add("scene.write", w0, time.monotonic(),
-                   granule=spec.granule_id)
+            with tr.span("scene.write", granule=spec.granule_id):
+                result = self.runner.finalize(reader, state, self.progress)
+                written.append(
+                    write_scene_result(self.output_path(spec), result))
             granules_done += 1
             if self.progress is not None:
                 self.progress.note_granule_done()

@@ -43,7 +43,7 @@ import numpy as np
 
 from repro.core import ychg
 from repro.engine import Engine
-from repro.obs import maybe_trace
+from repro.obs import maybe_trace, use_trace
 from repro.scene.granule import GranuleReader
 from repro.scene.result import SceneResult
 
@@ -250,7 +250,9 @@ class SceneRunner:
         the offline path. When tracing is on, each stack leaves
         ``scene.read`` / ``scene.compute`` (stream wait, which overlaps
         the *next* read by design) / ``scene.stitch`` spans plus one
-        ``scene.finalize`` span on the trace.
+        ``scene.finalize`` span on the trace. ``scene.sync``, the wait for
+        the stack's runs, nests in ``scene.stitch``; the engine's
+        ``engine.put`` / ``engine.dispatch`` nest in ``scene.compute``.
         """
         tr = trace if trace is not None else maybe_trace(process="scene")
         own = trace is None
@@ -261,34 +263,37 @@ class SceneRunner:
             t = state.next_tile
             while t < reader.n_tiles:
                 n = min(self.stack_tiles, reader.n_tiles - t)
-                r0 = time.monotonic()
-                s = reader.read_stack(t, n)
-                tr.add("scene.read", r0, time.monotonic(),
-                       granule=reader.granule_id, tile=t, tiles=n)
+                with tr.span("scene.read", granule=reader.granule_id,
+                             tile=t, tiles=n):
+                    s = reader.read_stack(t, n)
                 pending.append(s)
                 yield s
                 t += n
 
         try:
-            t_wait = time.monotonic()
-            for res in self.engine.analyze_stream(stacks()):
-                t_got = time.monotonic()
-                stack = pending.popleft()
-                tr.add("scene.compute", t_wait, t_got,
-                       granule=reader.granule_id, tiles=stack.shape[0])
-                s0 = time.monotonic()
-                self.update(state, stack, np.asarray(res.runs))
-                s1 = time.monotonic()
-                tr.add("scene.stitch", s0, s1, granule=reader.granule_id)
-                if progress is not None:
-                    progress.note_stitch(s1 - s0)
-                    progress.note_tiles(stack.shape[0])
+            with use_trace(tr):
                 t_wait = time.monotonic()
-            f0 = time.monotonic()
-            result = self.finalize(reader, state, progress)
-            tr.add("scene.finalize", f0, time.monotonic(),
-                   granule=reader.granule_id)
-            return result
+                for res in self.engine.analyze_stream(stacks()):
+                    t_got = time.monotonic()
+                    stack = pending.popleft()
+                    # the wait spans the stream's resumptions, so it is
+                    # recorded from its edges (no profiler annotation)
+                    tr.add("scene.compute", t_wait, t_got,
+                           granule=reader.granule_id, tiles=stack.shape[0])
+                    with tr.span("scene.stitch",
+                                 granule=reader.granule_id) as sp:
+                        s0 = time.monotonic()
+                        with tr.span("scene.sync", bytes=res.runs.nbytes):
+                            runs = np.asarray(res.runs)
+                        self.update(state, stack, runs)
+                        s1 = time.monotonic()
+                        sp.stamp(s0, s1)
+                    if progress is not None:
+                        progress.note_stitch(s1 - s0)
+                        progress.note_tiles(stack.shape[0])
+                    t_wait = time.monotonic()
+            with tr.span("scene.finalize", granule=reader.granule_id):
+                return self.finalize(reader, state, progress)
         finally:
             if own:
                 tr.finish()

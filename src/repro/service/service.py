@@ -48,7 +48,7 @@ import jax
 
 from repro.engine import Engine, YCHGResult
 from repro.engine.ops import PIPELINE_SEP, pipeline_op_key, split_pipeline_key, validate_pipeline
-from repro.obs import NULL_TRACE, maybe_trace
+from repro.obs import NULL_TRACE, maybe_trace, use_trace
 from repro.service.batching import (
     Bucket,
     crop_for,
@@ -573,13 +573,17 @@ class YCHGService:
                 for r in requests:
                     r.trace.add(f"pipeline.{name}", s0, s1)
 
-            result = self.engine.run_pipeline(
-                x, split_pipeline_key(op_key), valid_hw=hw,
-                on_stage=_stage_span)
+            with use_trace(requests[0].trace):
+                result = self.engine.run_pipeline(
+                    x, split_pipeline_key(op_key), valid_hw=hw,
+                    on_stage=_stage_span)
         elif op_key == self.engine.op:
-            result = self.engine.analyze_batch(x)  # async dispatch
+            # the engine's dispatch span joins the first rider's trace
+            with use_trace(requests[0].trace):
+                result = self.engine.analyze_batch(x)  # async dispatch
         else:
-            result = self.engine.analyze_batch(x, op=op_key)
+            with use_trace(requests[0].trace):
+                result = self.engine.analyze_batch(x, op=op_key)
         t1 = time.monotonic()
         self._recorder.observe_stage("flush", bucket, t1 - t0)
         for r in requests:

@@ -430,3 +430,99 @@ def test_fused_backend_accepts_device_arrays_without_host_copy():
     out = jax.jit(lambda x: run(x, cfg).n_hyperedges)(imgs)
     np.testing.assert_array_equal(
         np.asarray(out), np.asarray(ychg.analyze(imgs).n_hyperedges))
+
+
+# ------------------------------------------------------------ stage spans
+
+
+@pytest.fixture
+def traced():
+    from repro import obs
+
+    obs.configure(enabled=True)
+    obs.recorder().clear()
+    yield obs
+    obs.configure(enabled=True)
+    obs.recorder().clear()
+
+
+def _recorded(obs):
+    return [(tr.process, name, meta)
+            for tr in obs.recorder().traces()
+            for name, _, _, meta in tr.spans()]
+
+
+def test_bare_analyze_to_host_records_put_dispatch_fetch(traced):
+    img = (np.random.default_rng(5).random((12, 20)) < 0.5).astype(np.uint8)
+    Engine().analyze(img).to_host()
+    got = _recorded(traced)     # a trace of its own for each
+    assert [(p, n) for p, n, _ in got] == [
+        ("engine", "engine.put"), ("engine", "engine.dispatch"),
+        ("engine", "engine.fetch")]
+    assert len(traced.recorder().traces()) == 3
+    put, dispatch, fetch = (m for _, _, m in got)
+    assert put == {"bytes": img.nbytes}
+    assert dispatch == {"backend": "jax", "op": "ychg", "px": img.size}
+    # four int32 and one bool field a column, two int32 totals
+    assert fetch["bytes"] == 20 * (4 * 4 + 1) + 2 * 4
+
+
+def test_device_input_records_no_put_and_joins_current_trace(traced):
+    x = jnp.zeros((2, 8, 16), jnp.uint8)
+    tr = traced.Trace(process="tier")
+    with traced.use_trace(tr):
+        Engine().analyze_batch(x).to_host()
+    assert [s[0] for s in tr.spans()] == ["engine.dispatch", "engine.fetch"]
+    assert traced.recorder().traces() == []      # the tier finishes its own
+
+
+def test_stream_and_pipeline_spans(traced):
+    imgs = (np.random.default_rng(6).random((3, 8, 16)) < 0.5).astype(np.uint8)
+    tr = traced.Trace(process="tier")
+    with traced.use_trace(tr):
+        list(Engine().analyze_stream([imgs[0], imgs[1:]]))
+        Engine().run_pipeline(imgs, ["denoise", "ychg"])
+    names = [(s[0], s[3].get("op")) for s in tr.spans()]
+    assert names == [("engine.put", None), ("engine.dispatch", "ychg"),
+                     ("engine.put", None), ("engine.dispatch", "ychg"),
+                     ("engine.put", None), ("engine.dispatch", "denoise"),
+                     ("engine.dispatch", "ychg")]
+
+
+def test_ccl_and_denoise_to_host_record_fetch(traced):
+    img = (np.random.default_rng(7).random((8, 8)) < 0.5).astype(np.uint8)
+    for op in ("ccl", "denoise"):
+        Engine(op=op).analyze(img).to_host()
+    fetches = [m for _, n, m in _recorded(traced) if n == "engine.fetch"]
+    assert fetches == [{"bytes": 8 * 8 * 4 + 4}, {"bytes": 8 * 8 * 4}]
+
+
+def test_dispatch_span_and_histogram_share_clock_reads(traced, monkeypatch):
+    seen = []
+    note = registry.note_dispatch
+
+    def spy(name, seconds, op="ychg"):
+        seen.append(seconds)
+        note(name, seconds, op)
+
+    monkeypatch.setattr(registry, "note_dispatch", spy)
+    tr = traced.Trace()
+    with traced.use_trace(tr):
+        Engine().analyze_batch(np.ones((2, 4, 8), np.uint8))
+    (span,) = [s for s in tr.spans() if s[0] == "engine.dispatch"]
+    assert seen == [span[2] - span[1]]
+
+
+def test_engine_records_nothing_with_tracing_off(traced, monkeypatch):
+    from repro.obs import trace as trace_mod
+
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            opened.append(name)
+
+    monkeypatch.setattr(trace_mod, "_ANNOTATION", Annotation)
+    traced.configure(enabled=False)
+    Engine().analyze(np.ones((4, 8), np.uint8)).to_host()
+    assert traced.recorder().traces() == [] and opened == []

@@ -365,3 +365,123 @@ def test_stage_histograms_and_bucket_labels():
                                             ("dtype", "uint8"))
     assert bucket_labels(None) == ()
     assert bucket_labels("odd") == (("bucket", "odd"),)
+
+
+# ------------------------------------- profiler annotations, current trace
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records what opens."""
+
+    log: list = []
+
+    def __init__(self, name, **kwargs):
+        assert not kwargs          # the name alone: the cheap native path
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name))
+
+
+@pytest.fixture
+def fake_annotation(monkeypatch):
+    from repro.obs import trace as trace_mod
+
+    _FakeAnnotation.log = []
+    monkeypatch.setattr(trace_mod, "_ANNOTATION", _FakeAnnotation)
+    return _FakeAnnotation.log
+
+
+def test_live_span_is_a_profiler_annotation(tracing, fake_annotation):
+    tr = Trace(process="test")
+    with tr.span("outer", k=1):
+        with tr.span("inner"):
+            pass
+    tr.add("retro", 1.0, 2.0)      # after the fact: no annotation
+    assert fake_annotation == [("enter", "outer"), ("enter", "inner"),
+                               ("exit", "inner"), ("exit", "outer")]
+    assert [s[0] for s in tr.spans()] == ["inner", "outer", "retro"]
+
+
+def test_tracing_off_records_nothing_and_opens_no_annotation(
+        tracing, fake_annotation):
+    obs.configure(enabled=False)
+    with obs.join_trace("engine") as tr:
+        assert tr is obs.NULL_TRACE
+    with maybe_trace().span("x") as sp:
+        sp.stamp(1.0, 2.0)
+    with obs.use_trace(maybe_trace()), obs.join_trace("engine") as tr:
+        with tr.span("y"):
+            pass
+    assert fake_annotation == []
+    assert obs.recorder().traces() == []
+
+
+def test_stamp_keeps_the_callers_clock_reads(tracing):
+    tr = Trace()
+    with tr.span("timed") as sp:
+        sp.stamp(3.0, 4.5)
+    assert tr.spans() == [("timed", 3.0, 4.5, {})]
+
+
+def _joined():
+    with obs.join_trace("engine") as tr:
+        tr.add("s", 0.0, 1.0)
+    return tr
+
+
+def test_join_trace_prefers_the_current_trace(tracing):
+    own = _joined()
+    assert own.process == "engine"
+    assert obs.recorder().traces() == [own]     # finished by the block
+    granule = Trace(process="scene")
+    with obs.use_trace(granule) as cur:
+        assert cur is granule and _joined() is granule
+        with obs.use_trace(obs.NULL_TRACE):    # a tier may opt out
+            assert _joined() is obs.NULL_TRACE
+        assert _joined() is granule
+    assert obs.recorder().traces() == [own]     # the tier finishes its own
+    assert _joined() not in (own, granule)
+
+
+def test_current_trace_is_per_thread(tracing):
+    seen = []
+    granule = Trace()
+    with obs.use_trace(granule):
+        t = threading.Thread(target=lambda: seen.append(_joined()))
+        t.start()
+        t.join(timeout=60)
+    assert not t.is_alive() and seen[0] is not granule
+
+
+def test_obs_imports_and_traces_without_jax():
+    """The frontend and router import ``repro.obs`` without jax: with
+    jax blocked, it imports, spans record, and no annotation is sought."""
+    import subprocess
+    import sys
+    import textwrap
+    from pathlib import Path
+
+    src = Path(__file__).resolve().parents[1] / "src"
+    code = textwrap.dedent("""
+        import sys
+        sys.modules["jax"] = None          # any import of jax now fails
+        from repro import obs
+        tr = obs.Trace()
+        with obs.use_trace(tr), obs.join_trace("engine") as cur:
+            with cur.span("s"):
+                pass
+        assert [s[0] for s in tr.spans()] == ["s"]
+        assert not any(m.startswith("jax") and sys.modules[m] is not None
+                       for m in sys.modules)
+        print("ok")
+    """)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60,
+                         env={"PYTHONPATH": str(src), "PATH": "/usr/bin:/bin"})
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "ok"

@@ -378,3 +378,55 @@ def test_online_tiles_agree_with_offline_scene():
         text = client.metrics_text()
     assert "ychg_scene_tiles_done" in text
     assert "ychg_scene_resumes_total" in text
+
+
+# ------------------------------------------------------------- stage spans
+
+
+@pytest.fixture
+def traced():
+    from repro import obs
+
+    obs.configure(enabled=True)
+    obs.recorder().clear()
+    yield obs
+    obs.configure(enabled=True)
+    obs.recorder().clear()
+
+
+def test_bulk_job_stage_spans_nest_per_stack(tmp_path, traced):
+    """Each stack: read, then put / dispatch / sync inside compute, then
+    stitch, all on the granule's trace; the engine opens none of its own."""
+    manifest = synthetic_manifest(1, 21, 10, seed=21, cell=4)
+    _job(tmp_path, "spans", manifest, tile_h=8, stack_tiles=2,
+         checkpoint_every=8).run()
+    (tr,) = traced.recorder().traces()
+    assert tr.process == "scene"
+    spans = sorted(tr.spans(), key=lambda s: (s[1], -s[2]))
+    names = [s[0] for s in spans]
+    stack = ["scene.read", "scene.compute", "engine.put",
+             "engine.dispatch", "scene.sync", "scene.stitch"]
+    # 3 strips of 8 rows in stacks of 2: two stacks, then the granule ends
+    assert names == stack * 2 + ["scene.write", "scene.checkpoint"]
+    for i in (0, 6):
+        _, c0, c1, meta = spans[i + 1]
+        assert meta == {"granule": "granule_0021", "tiles": (2, 1)[i // 6]}
+        for name, a, b, _ in spans[i + 2:i + 5]:
+            assert c0 <= a <= b <= c1, name
+        assert spans[i][2] <= c0 and c1 <= spans[i + 5][1]
+    assert spans[4][3] == {"bytes": 2 * 10 * 4}        # the (2, W) runs
+
+
+def test_scene_runner_stage_spans(traced):
+    reader = GranuleReader.open(GranuleSpec("s", 21, 10, seed=3, cell=4), 8)
+    SceneRunner(Engine(), stack_tiles=2).analyze_scene(reader)
+    (tr,) = traced.recorder().traces()
+    names = [s[0] for s in tr.spans()]
+    assert names.count("scene.sync") == names.count("scene.stitch") == 2
+    assert names.count("engine.put") == names.count("engine.dispatch") == 2
+    assert names[-1] == "scene.finalize"
+    by = {}
+    for name, a, b, _ in tr.spans():
+        by.setdefault(name, []).append((a, b))
+    for (a, b), (s0, s1) in zip(by["scene.sync"], by["scene.stitch"]):
+        assert s0 <= a <= b <= s1               # the wait is part of stitch

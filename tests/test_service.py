@@ -658,3 +658,27 @@ def test_registry_call_counters():
     engine.analyze_batch(np.zeros((2, 4, 4), np.uint8))
     assert registry.call_count("jax") == 2
     assert registry.call_count("fused") == 0
+
+
+def test_flush_dispatch_span_joins_the_riders_trace():
+    """The engine's dispatch span of a flush lands on its first rider's
+    trace: served requests open no engine traces of their own."""
+    from repro import obs
+
+    obs.configure(enabled=True)
+    obs.recorder().clear()
+    try:
+        with YCHGService(config=ServiceConfig(
+                bucket_sides=(64,), max_batch=4, max_delay_ms=1.0)) as svc:
+            trace = obs.Trace(process="test")
+            svc.submit(_mask((20, 30), seed=41), trace=trace).result(
+                timeout=TIMEOUT)
+            stages = ("engine.put", "engine.dispatch", "engine.fetch")
+            (name, a, b, meta), = [s for s in trace.spans()
+                                   if s[0] in stages]
+            assert name == "engine.dispatch" and meta["op"] == "ychg"
+            flush = [s for s in trace.spans() if s[0] == "scheduler.flush"]
+            assert flush[0][1] <= a <= b <= flush[0][2]
+        assert all(t.process != "engine" for t in obs.recorder().traces())
+    finally:
+        obs.recorder().clear()

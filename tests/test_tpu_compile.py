@@ -63,3 +63,23 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     x = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=one_chip)
     compiled = jax.jit(functools.partial(fn, interpret=False)).lower(x).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+NAMED = {
+    # the kernel's name heads its custom-call instruction, and with it the
+    # kernel's event on the XLA Ops line of a chip trace
+    "ychg_fused_full": (ychg_fused.fused_analyze_pallas, (4, 256, 5416)),
+    "ychg_fused_streamed": (ychg_fused.fused_analyze_streamed,
+                            (1, 8120, 5416)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NAMED))
+def test_fused_kernels_carry_their_names_on_v5e(one_chip, name):
+    fn, shape = NAMED[name]
+    x = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=one_chip)
+    lowered = jax.jit(functools.partial(fn, interpret=False)).lower(x)
+    text = lowered.compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert len(calls) == 1 and calls[0].startswith(f"%{name}")
