@@ -20,9 +20,15 @@ Every verb returns the op's result pytree (``YCHGResult`` for yCHG — see
 arrays that can cross ``jit``/``shard_map`` boundaries and never leave the
 device implicitly. ``.to_host()`` produces the legacy host dict.
 
-Tracing: a host input's transfer is an ``engine.put`` span, each backend
-run an ``engine.dispatch`` span, and every ``to_host()`` an
-``engine.fetch`` span. They join the trace the calling tier made current
+Ingest: a C-contiguous uint8/int8 host mask of at least
+``ingest.MIN_BYTES`` whose width is a multiple of 4 is shipped as 32-bit
+words and restored on the device (``repro.kernels.ingest``), the same
+array in dtype, shape and bytes; every other input is shipped as it is.
+
+Tracing: a host input's transfer is an ``engine.put`` span (meta
+``words``: 1 when it went as words), each backend run an
+``engine.dispatch`` span, and every ``to_host()`` an ``engine.fetch``
+span. They join the trace the calling tier made current
 (``repro.obs.use_trace``), or each opens and finishes a trace of its own.
 
 ``YCHGEngine`` remains as a deprecation shim over ``Engine`` (same policy,
@@ -45,6 +51,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.ychg import YCHGSummary
 from repro.engine import registry
+from repro.kernels import ingest
 from repro.obs import join_trace
 
 Array = jax.Array
@@ -155,6 +162,18 @@ def _from_summary(s: YCHGSummary, batched: bool) -> YCHGResult:
     return r
 
 
+def _word_view(host: np.ndarray) -> Optional[np.ndarray]:
+    """``host`` as (B*H, W/4) uint32 words, a free view, when it is a
+    C-contiguous uint8/int8 (B, H, W) stack of at least
+    ``ingest.MIN_BYTES`` whose width is a multiple of 4; else None (the
+    array is shipped as it is)."""
+    if (host.dtype in (np.uint8, np.int8) and host.ndim == 3
+            and host.nbytes >= ingest.MIN_BYTES and host.shape[-1] % 4 == 0
+            and host.flags.c_contiguous):
+        return host.reshape(-1, host.shape[-1]).view(np.uint32)
+    return None
+
+
 def _zero_pad_region(x: Array, valid_hw: Array) -> Array:
     """Zero rows >= h and cols >= w per image (valid_hw: (B, 2) int32).
 
@@ -247,13 +266,19 @@ class Engine:
             x = imgs[None] if single else imgs
         else:
             host = np.asarray(imgs)
-            # the runtime's relayout and the copy it starts, and the eager
-            # reshape that makes a lone mask the B=1 stack the backend takes
-            with join_trace("engine") as tr, tr.span("engine.put",
-                                                     bytes=host.nbytes):
-                x = jnp.asarray(host)
-                if single:
-                    x = x[None]
+            if single:
+                host = host[None]
+            words = _word_view(host)
+            # the runtime's relayout and the copy it starts; for a byte
+            # mask shipped as words (meta words=1) also the unpack's dispatch
+            with join_trace("engine") as tr, tr.span(
+                    "engine.put", bytes=host.nbytes,
+                    words=int(words is not None)):
+                if words is None:
+                    x = jnp.asarray(host)
+                else:
+                    x = ingest.ship(words, host.shape[0], host.dtype,
+                                    interpret=self.config.interpret)
         if self._cast_dtype is not None and x.dtype != self._cast_dtype:
             x = x.astype(self._cast_dtype)
         return x
@@ -297,12 +322,9 @@ class Engine:
             # pull and ingest (start the transfer of) item n+1 first ...
             try:
                 item = next(it)
-                x = self._ingest(item)
-                if x.ndim == 2:
-                    x, batched = x[None], False
-                elif x.ndim == 3:
-                    batched = True
-                else:
+                batched = np.ndim(item) != 2
+                x = self._ingest(item, single=not batched)
+                if x.ndim != 3:
                     raise ValueError(
                         f"stream items must be (H, W) or (B, H, W), "
                         f"got {x.shape}"

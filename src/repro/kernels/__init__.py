@@ -21,6 +21,8 @@ fused backend). See ``repro.engine`` for the migration table.
                    carry row for images past the VMEM budget
   ychg_packed.py   1-bit row packing (8x less HBM traffic on the scan)
   ccl.py, denoise.py  whole-image kernels of the other ops
+  ingest.py        a host byte mask shipped as 32-bit words and unpacked
+                   on the device (the engine's ingest of host masks)
   platform.py      interpret-or-compile from the platform, in one place;
                    scoped-VMEM limits for the whole-image kernels
   ops.py           jit'd wrappers;

@@ -432,6 +432,114 @@ def test_fused_backend_accepts_device_arrays_without_host_copy():
         np.asarray(out), np.asarray(ychg.analyze(imgs).n_hyperedges))
 
 
+# ------------------------------------------------------------ word ingest
+
+
+@pytest.fixture
+def words_at_any_size(monkeypatch):
+    """Ship masks of any size as words (the tests' masks are below the
+    floor the chip measured)."""
+    from repro.kernels import ingest
+
+    monkeypatch.setattr(ingest, "MIN_BYTES", 1)
+
+
+def _bytes(shape, dtype, seed=0):
+    """Random bytes of every value, as ``dtype`` (int8 holds negatives)."""
+    raw = np.random.default_rng(seed).integers(0, 256, shape, dtype=np.uint8)
+    return raw.view(dtype)
+
+
+@pytest.mark.parametrize("dtype", [np.uint8, np.int8])
+@pytest.mark.parametrize("shape", [(3, 37, 260), (37, 260), (1, 1, 4),
+                                   (2, 40, 1028), (12, 16)])
+def test_word_ingest_is_bit_identical(dtype, shape, monkeypatch,
+                                     words_at_any_size):
+    """A C-contiguous byte mask whose width is a multiple of 4 goes as
+    words (odd H, W/4 not a multiple of 128 included) and lands as the
+    very array the old path made: dtype, (B, H, W) shape, every byte."""
+    from repro.engine import engine as engine_mod
+
+    shipped = []
+    ship = engine_mod.ingest.ship
+    monkeypatch.setattr(engine_mod.ingest, "ship",
+                        lambda w, *a, **k: shipped.append(w.shape) or
+                        ship(w, *a, **k))
+    host = _bytes(shape, dtype, seed=sum(shape))
+    x = Engine()._ingest(host, single=len(shape) == 2)
+    want = host if len(shape) == 3 else host[None]
+    assert x.dtype == want.dtype and x.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(x), want)
+    assert shipped == [(want.shape[0] * want.shape[1], want.shape[2] // 4)]
+
+
+@pytest.mark.parametrize("case", ["width5", "strided", "bool", "float32",
+                                  "fortran", "device", "below_floor"])
+def test_inputs_outside_the_gate_take_the_old_path(case, monkeypatch):
+    from repro.engine import engine as engine_mod
+
+    def refuse(*_a, **_k):
+        raise AssertionError("shipped as words")
+
+    monkeypatch.setattr(engine_mod.ingest, "ship", refuse)
+    if case != "below_floor":
+        monkeypatch.setattr(engine_mod.ingest, "MIN_BYTES", 1)
+    base = _bytes((2, 9, 16), np.uint8, seed=3)
+    host = {"width5": _bytes((2, 9, 5), np.uint8),
+            "strided": base[:, :, ::2],         # width 8, not contiguous
+            "bool": base > 127,
+            "float32": base.astype(np.float32),
+            "fortran": np.asfortranarray(base),
+            "device": jnp.asarray(base), "below_floor": base}[case]
+    x = Engine()._ingest(host)
+    want = jnp.asarray(host)
+    assert x.dtype == want.dtype and x.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(x), np.asarray(want))
+
+
+def _reference(op, stack):
+    from repro.engine import ops as engine_ops
+
+    return engine_ops.get_op(op).reference(jnp.asarray(stack))
+
+
+@pytest.mark.parametrize("dtype", [np.uint8, np.int8])
+@pytest.mark.parametrize("verb", ["analyze", "analyze_batch.ychg",
+                                  "analyze_batch.ccl", "analyze_batch.denoise",
+                                  "analyze_stream", "run_pipeline"])
+def test_verbs_on_word_ingest_match_the_reference(verb, dtype,
+                                                  words_at_any_size):
+    from repro.engine import ops as engine_ops
+
+    stack = _bytes((2, 11, 20), dtype, seed=8)
+    if dtype == np.uint8:
+        stack = (stack > 127).astype(np.uint8)     # a 0/1 mask
+    engine = Engine()
+    if verb == "analyze":
+        got, op = [engine.analyze(stack[0])], "ychg"
+        wants = [_reference(op, stack[:1])]
+    elif verb.startswith("analyze_batch"):
+        op = verb.split(".")[1]
+        got, wants = [engine.analyze_batch(stack, op=op)], [
+            _reference(op, stack)]
+    elif verb == "analyze_stream":
+        op = "ychg"
+        got = list(engine.analyze_stream([stack[0], stack]))
+        wants = [_reference(op, stack[:1]), _reference(op, stack)]
+    else:
+        op = "ychg"
+        got = [engine.run_pipeline(stack, ["denoise", "ychg"])]
+        wants = [_reference(op, _reference("denoise", stack).image)]
+    for res, want in zip(got, wants):
+        for f in engine_ops.get_op(op).fields:
+            g, w = np.asarray(getattr(res.to_summary(), f)), np.asarray(
+                getattr(want, f))
+            if verb in ("analyze", "analyze_stream") and g.ndim < w.ndim:
+                w = w[0]                        # the B=1 view of a lone mask
+            assert g.dtype == w.dtype, f
+            np.testing.assert_array_equal(g, w, err_msg=f)
+
+
 # ------------------------------------------------------------ stage spans
 
 
@@ -452,7 +560,8 @@ def _recorded(obs):
             for name, _, _, meta in tr.spans()]
 
 
-def test_bare_analyze_to_host_records_put_dispatch_fetch(traced):
+def test_bare_analyze_to_host_records_put_dispatch_fetch(traced,
+                                                        words_at_any_size):
     img = (np.random.default_rng(5).random((12, 20)) < 0.5).astype(np.uint8)
     Engine().analyze(img).to_host()
     got = _recorded(traced)     # a trace of its own for each
@@ -461,7 +570,7 @@ def test_bare_analyze_to_host_records_put_dispatch_fetch(traced):
         ("engine", "engine.fetch")]
     assert len(traced.recorder().traces()) == 3
     put, dispatch, fetch = (m for _, _, m in got)
-    assert put == {"bytes": img.nbytes}
+    assert put == {"bytes": img.nbytes, "words": 1}
     assert dispatch == {"backend": "jax", "op": "ychg", "px": img.size}
     # four int32 and one bool field a column, two int32 totals
     assert fetch["bytes"] == 20 * (4 * 4 + 1) + 2 * 4
@@ -476,7 +585,7 @@ def test_device_input_records_no_put_and_joins_current_trace(traced):
     assert traced.recorder().traces() == []      # the tier finishes its own
 
 
-def test_stream_and_pipeline_spans(traced):
+def test_stream_and_pipeline_spans(traced, words_at_any_size):
     imgs = (np.random.default_rng(6).random((3, 8, 16)) < 0.5).astype(np.uint8)
     tr = traced.Trace(process="tier")
     with traced.use_trace(tr):
@@ -487,6 +596,22 @@ def test_stream_and_pipeline_spans(traced):
                      ("engine.put", None), ("engine.dispatch", "ychg"),
                      ("engine.put", None), ("engine.dispatch", "denoise"),
                      ("engine.dispatch", "ychg")]
+    # 16 columns of uint8: every put went as words
+    assert [s[3]["words"] for s in tr.spans() if s[0] == "engine.put"] == [
+        1, 1, 1]
+
+
+@pytest.mark.parametrize("mask, words", [
+    (np.ones((4, 8), np.uint8), 1), (np.ones((4, 8), np.int8), 1),
+    (np.ones((4, 6), np.uint8), 0), (np.ones((4, 8), bool), 0),
+    (np.ones((4, 8), np.float32), 0)])
+def test_put_span_counts_word_shipping(traced, mask, words, monkeypatch):
+    from repro.kernels import ingest
+
+    monkeypatch.setattr(ingest, "MIN_BYTES", mask.nbytes)   # at the floor
+    Engine().analyze(mask)
+    (put,) = [m for _, n, m in _recorded(traced) if n == "engine.put"]
+    assert put == {"bytes": mask.nbytes, "words": words}
 
 
 def test_ccl_and_denoise_to_host_record_fetch(traced):

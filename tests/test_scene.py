@@ -415,6 +415,8 @@ def test_bulk_job_stage_spans_nest_per_stack(tmp_path, traced):
             assert c0 <= a <= b <= c1, name
         assert spans[i][2] <= c0 and c1 <= spans[i + 5][1]
     assert spans[4][3] == {"bytes": 2 * 10 * 4}        # the (2, W) runs
+    # 10 columns are not whole words: the stacks went as bytes
+    assert spans[2][3] == {"bytes": 2 * 8 * 10, "words": 0}
 
 
 def test_scene_runner_stage_spans(traced):

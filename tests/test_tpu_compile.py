@@ -20,7 +20,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ccl, denoise, ychg_fused
+from repro.kernels import ccl, denoise, ingest, ychg_fused
 
 
 @pytest.fixture(scope="module")
@@ -83,3 +83,24 @@ def test_fused_kernels_carry_their_names_on_v5e(one_chip, name):
     calls = [line.strip() for line in text.splitlines()
              if "custom_call_target=\"tpu_custom_call\"" in line]
     assert len(calls) == 1 and calls[0].startswith(f"%{name}")
+
+
+@pytest.mark.parametrize("shape, batch", [((21000, 5250), 1),
+                                          ((1024, 1354), 4),
+                                          ((8120, 1354), 1)])
+def test_ingest_unpack_reads_the_words_in_place_on_v5e(one_chip, shape,
+                                                       batch):
+    """Words of the 21000^2 scene, of a bulk stack of four 256-row strips
+    5416 wide and of a whole granule, in the layout the runtime gives
+    them, unpack in one named kernel that reads them through a bitcast:
+    no copy of the words, no temporary."""
+    x = jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(functools.partial(
+        ingest.unpack_words, batch=batch,
+        interpret=False)).lower(x).compile()
+    text = compiled.as_text()
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert len(calls) == 1 and calls[0].startswith("%ingest_unpack")
+    assert "copy(%words" not in text and "bitcast(%words" in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
