@@ -138,11 +138,22 @@ jax.tree_util.register_dataclass(
 def fetch(result: Any, fields: Sequence[str]) -> Dict[str, np.ndarray]:
     """Every op result's ``to_host()``: the wait for the device, the
     squeeze of a B=1 view and the device->host copy, under one
-    ``engine.fetch`` span (meta: the result's device bytes)."""
+    ``engine.fetch`` span (meta: the result's device bytes). A result
+    that carries ``diag`` (the band path of ``ccl``) brings it back in
+    the same copy and puts its largest passes and merge rounds over the
+    batch on the span as ``sweeps`` and ``merge_rounds``."""
+    diag = getattr(result, "diag", None)
     with join_trace("engine") as tr, tr.span("engine.fetch", bytes=sum(
-            getattr(result, f).nbytes for f in fields)):
+            getattr(result, f).nbytes for f in fields)) as sp:
         s = result.to_summary()
-        return {f: np.asarray(getattr(s, f)) for f in fields}
+        if diag is None:
+            return {f: np.asarray(getattr(s, f)) for f in fields}
+        *host, counts = jax.device_get([getattr(s, f) for f in fields]
+                                       + [diag])
+        if tr.enabled:
+            sweeps, rounds = counts.max(axis=0).tolist()
+            sp.meta.update(sweeps=sweeps, merge_rounds=rounds)
+        return dict(zip(fields, host))
 
 
 def _from_summary(s: YCHGSummary, batched: bool) -> YCHGResult:

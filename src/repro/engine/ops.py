@@ -58,6 +58,9 @@ class CCLResult:
     n_components: Array  # (B,) int32
     batched: bool = dataclasses.field(default=True,
                                       metadata=dict(static=True))
+    # the band path's (B, 2) passes and merge rounds (``CCLSummary.diag``);
+    # not a result field: ``to_host()`` puts it on the ``engine.fetch`` span
+    diag: Optional[Array] = None
 
     @property
     def batch_size(self) -> int:
@@ -106,7 +109,8 @@ class DenoiseResult:
 
 
 jax.tree_util.register_dataclass(
-    CCLResult, data_fields=["labels", "n_components"], meta_fields=["batched"]
+    CCLResult, data_fields=["labels", "n_components", "diag"],
+    meta_fields=["batched"]
 )
 jax.tree_util.register_dataclass(
     DenoiseResult, data_fields=["image"], meta_fields=["batched"]
@@ -209,7 +213,8 @@ register_op(OpSpec(
     summary_type=_ccl.CCLSummary,
     result_type=CCLResult,
     from_summary=lambda s, batched: CCLResult(
-        labels=s.labels, n_components=s.n_components, batched=batched),
+        labels=s.labels, n_components=s.n_components, batched=batched,
+        diag=s.diag),
     reference=_ccl.labels,
     chain_field="labels",   # nonzero labels = foreground downstream
 ))

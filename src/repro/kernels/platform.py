@@ -8,7 +8,8 @@ be rehearsed against a described TPU topology (``tests/test_tpu_compile.py``).
 
 Whole-image kernels (``ccl``, ``denoise``) also size their scoped-VMEM
 limit here, from the block they hold, and refuse a block that no limit
-can hold instead of letting Mosaic fail deep inside a compile.
+can hold instead of letting Mosaic fail deep inside a compile; ``ccl``
+takes the height of its bands of rows from the same arithmetic.
 """
 
 from __future__ import annotations
@@ -37,6 +38,11 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return bool(interpret)
 
 
+def _vmem_bytes_per_px(in_dtype, out_dtype, temp_bytes_per_px: int) -> int:
+    return (2 * (jnp.dtype(in_dtype).itemsize + jnp.dtype(out_dtype).itemsize)
+            + temp_bytes_per_px)
+
+
 def whole_image_vmem_limit(kernel: str, block_shape: tuple[int, ...],
                            in_dtype, out_dtype, temp_bytes_per_px: int) -> int:
     """Scoped-VMEM limit for a kernel holding one whole (1, H, W) image.
@@ -48,13 +54,27 @@ def whole_image_vmem_limit(kernel: str, block_shape: tuple[int, ...],
     the block cannot fit; no other backend is substituted.
     """
     px = math.prod(block_shape)
-    per_px = (2 * (jnp.dtype(in_dtype).itemsize + jnp.dtype(out_dtype).itemsize)
-              + temp_bytes_per_px)
-    need = px * per_px + _VMEM_MARGIN
+    need = px * _vmem_bytes_per_px(in_dtype, out_dtype,
+                                   temp_bytes_per_px) + _VMEM_MARGIN
     if need > VMEM_CAP_BYTES:
         raise VmemBudgetError(
-            f"{kernel}: a whole-image block of shape {tuple(block_shape)} "
+            f"{kernel}: a block of shape {tuple(block_shape)} "
             f"{jnp.dtype(in_dtype).name} needs about {need / 2**20:.1f} MiB "
-            f"of VMEM, over the {VMEM_CAP_BYTES >> 20} MiB limit; an image "
-            f"this large needs a row-tiled kernel, which is not implemented")
+            f"of VMEM, over the {VMEM_CAP_BYTES >> 20} MiB limit; ccl labels "
+            f"larger images in bands of rows, denoise has no row-tiled "
+            f"kernel")
     return max(need, _DEFAULT_SCOPED_VMEM)
+
+
+def max_block_rows(kernel: str, width: int, in_dtype, out_dtype,
+                   temp_bytes_per_px: int, multiple: int) -> int:
+    """The most rows, a multiple of ``multiple``, of a (1, rows, width)
+    block that :func:`whole_image_vmem_limit` admits. Raises
+    :class:`VmemBudgetError` when not even ``multiple`` rows fit."""
+    per_px = _vmem_bytes_per_px(in_dtype, out_dtype, temp_bytes_per_px)
+    rows = (VMEM_CAP_BYTES - _VMEM_MARGIN) // (width * per_px)
+    rows -= rows % multiple
+    if rows == 0:
+        whole_image_vmem_limit(kernel, (1, multiple, width), in_dtype,
+                               out_dtype, temp_bytes_per_px)
+    return rows

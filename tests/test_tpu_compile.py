@@ -104,3 +104,23 @@ def test_ingest_unpack_reads_the_words_in_place_on_v5e(one_chip, shape,
     assert len(calls) == 1 and calls[0].startswith("%ingest_unpack")
     assert "copy(%words" not in text and "bitcast(%words" in text
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_ccl_band_path_compiles_for_v5e_at_a_mod44w_tile(one_chip):
+    """A 4800x4800 MOD44W tile is past the whole-image block, so
+    ``labels_pallas`` takes the band path: the band kernel twice, named
+    ``ccl_band_labels`` (labels) and ``ccl_rank_spread`` (ranks), on bands
+    of 600 rows 4864 lanes wide, and the whole program (merge and ranking
+    included) needs well under 2 GB."""
+    x = jax.ShapeDtypeStruct((1, 4800, 4800), jnp.uint8, sharding=one_chip)
+    compiled = jax.jit(functools.partial(
+        ccl.labels_pallas, interpret=False)).lower(x).compile()
+    calls = sorted(line.strip().removeprefix("ROOT ")
+                   for line in compiled.as_text().splitlines()
+                   if "custom_call_target=\"tpu_custom_call\"" in line)
+    assert [c.split(" ")[0].split(".")[0] for c in calls] == [
+        "%ccl_band_labels", "%ccl_rank_spread"]
+    assert all("s32[1,4800,4864]" in c for c in calls)
+    mem = compiled.memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.output_size_in_bytes
+            + mem.argument_size_in_bytes) < 2 << 30
