@@ -140,8 +140,9 @@ def fetch(result: Any, fields: Sequence[str]) -> Dict[str, np.ndarray]:
     squeeze of a B=1 view and the device->host copy, under one
     ``engine.fetch`` span (meta: the result's device bytes). A result
     that carries ``diag`` (the band path of ``ccl``) brings it back in
-    the same copy and puts its largest passes and merge rounds over the
-    batch on the span as ``sweeps`` and ``merge_rounds``."""
+    the same copy and puts its largest passes, merge rounds and distinct
+    seam labels over the batch on the span as ``sweeps``,
+    ``merge_rounds`` and ``seam_labels``."""
     diag = getattr(result, "diag", None)
     with join_trace("engine") as tr, tr.span("engine.fetch", bytes=sum(
             getattr(result, f).nbytes for f in fields)) as sp:
@@ -151,8 +152,9 @@ def fetch(result: Any, fields: Sequence[str]) -> Dict[str, np.ndarray]:
         *host, counts = jax.device_get([getattr(s, f) for f in fields]
                                        + [diag])
         if tr.enabled:
-            sweeps, rounds = counts.max(axis=0).tolist()
-            sp.meta.update(sweeps=sweeps, merge_rounds=rounds)
+            sweeps, rounds, seam_labels = counts.max(axis=0).tolist()
+            sp.meta.update(sweeps=sweeps, merge_rounds=rounds,
+                           seam_labels=seam_labels)
         return dict(zip(fields, host))
 
 

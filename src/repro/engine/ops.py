@@ -58,7 +58,8 @@ class CCLResult:
     n_components: Array  # (B,) int32
     batched: bool = dataclasses.field(default=True,
                                       metadata=dict(static=True))
-    # the band path's (B, 2) passes and merge rounds (``CCLSummary.diag``);
+    # the band path's (B, 3) passes, merge rounds and seam labels
+    # (``CCLSummary.diag``);
     # not a result field: ``to_host()`` puts it on the ``engine.fetch`` span
     diag: Optional[Array] = None
 

@@ -57,8 +57,9 @@ class CCLSummary:
 
     labels: Array        # (B, H, W) int32, 0 = background, 1..n per image
     n_components: Array  # (B,) int32
-    # band path only: (B, 2) int32 per image, the band kernel's passes
-    # (summed over bands) and the seam merge's rounds; read by the engine
+    # band path only: (B, 3) int32 per image, the band kernel's passes
+    # (summed over bands), the seam merge's rounds and the distinct
+    # nonzero labels on the seam rows; read by the engine
     diag: Array | None = None
 
 
@@ -212,10 +213,12 @@ def labels_pallas(stack: Array, *, interpret: bool | None = None) -> CCLSummary:
 # becomes the least index + 1 of its component inside the band. The labels
 # of vertically adjacent foreground pixels across each seam between bands
 # are pairs of one component; resolving them (``ccl_seam_merge``) gives
-# every band label the least label linked to it, the root of the whole
-# component. The roots' rank in row-major order is a running count, which
-# never falls along the order; seeded with it, and each seam row with the
-# rank of its component's root, the kernel runs again (``ccl_relabel``)
+# every band label on a seam row the least label linked to it, the root
+# of the whole component. Only labels on seam rows are ever linked, so the
+# merge's table holds those alone, not a label of every pixel. The
+# roots' rank in row-major order is a running count, which never falls
+# along the order; seeded with it, and each seam row with the rank of its
+# component's root, the kernel runs again (``ccl_relabel``)
 # and every pixel ends at its component's rank, the labels of
 # :func:`labels`. (The gathers of :func:`_canonicalize` over a MOD44W
 # tile, 24 M entries, take ~0.34 s each on a v5e.) The three are programs
@@ -432,53 +435,116 @@ def _resolve(a: Array, c: Array, *, size: int) -> tuple[Array, Array]:
         (jnp.arange(size, dtype=jnp.int32), jnp.int32(0)))
 
 
+def _number(keys: Array) -> tuple[Array, Array, Array]:
+    """(B, N) labels -> each one's compact id, the labels sorted, and the
+    places in that order where a value first occurs. A label's id is the
+    place of its value's first occurrence, so ids keep the labels' order
+    and the least label takes id 0. Two sorts number them, not a gather
+    or a scatter."""
+    at = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
+    ordered, perm = jax.lax.sort((keys, at), dimension=1, num_keys=1)
+    first = jnp.concatenate([jnp.ones_like(ordered[:, :1], dtype=bool),
+                             ordered[:, 1:] != ordered[:, :-1]], axis=1)
+    ids = jax.lax.cummax(jnp.where(first, at, 0), axis=1)
+    ids = jax.lax.sort((perm, ids), dimension=1, num_keys=1)[1]
+    return ids, ordered, first
+
+
+def _seam_rows(hp: int, band_rows: int) -> tuple[slice, slice]:
+    """The rows above and the rows below the seams between bands."""
+    return (slice(band_rows - 1, hp - 1, band_rows),
+            slice(band_rows, hp, band_rows))
+
+
+# A gather or scatter on the TPU costs by its indices, whatever the size of
+# the table it reads: the merge's loop runs over the pairs that link two
+# labels, moved to the front, and over this many places alone where every
+# image's fit them (a MOD44W tile has under a hundred).
+_SHORT_PAIRS = 1024
+
+
+def _linking_pairs(a: Array, c: Array,
+                   both: Array) -> tuple[Array, Array, Array]:
+    """(B, N) pair ends -> the pairs of two foreground ends, each run of
+    equal pairs once, moved in order to the front (the rest, background
+    pairs and repeats, after them), and how many there are per image."""
+    repeat = jnp.concatenate(
+        [jnp.zeros_like(both[:, :1]),
+         (a[:, 1:] == a[:, :-1]) & (c[:, 1:] == c[:, :-1])], axis=1)
+    kept = both & ~repeat
+    _, a, c = jax.lax.sort(((~kept).astype(jnp.int32), a, c), dimension=1,
+                           num_keys=1, is_stable=True)
+    return a, c, jnp.sum(kept, axis=1, dtype=jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("band_rows",))
 def ccl_seam_merge(lab: Array, *, band_rows: int) -> tuple[Array, Array]:
-    """Band labels -> per image, a table from each label to the least
-    label of its component, and the merge rounds it took ((B,) int32).
+    """Band labels -> per image, the root label of every pixel of the seam
+    rows (0 on background), and (B, 2) int32 counts: the merge's rounds
+    and the distinct nonzero labels on the seam rows.
 
-    The pairs are the labels of vertically adjacent foreground pixels
-    across each seam, at most W per seam; label 0 pairs with itself
-    where a seam pixel is background."""
-    b, hp, wp = lab.shape
-    above = lab[:, band_rows - 1:hp - 1:band_rows, :]
-    below = lab[:, band_rows::band_rows, :]
-    both = (above < _INF) & (below < _INF)
-    a = jnp.where(both, above, 0).reshape(b, -1)
-    c = jnp.where(both, below, 0).reshape(b, -1)
-    return jax.vmap(functools.partial(_resolve, size=hp * wp + 1))(a, c)
+    The labels of the two rows at each seam, 2 * seams * Wp of them, are
+    numbered in their sorted order (:func:`_number`), and the pairs of
+    vertically adjacent foreground pixels are resolved over a table of
+    those ids, not of every label of the image. The numbering keeps the
+    labels' order, so min-hooking takes the steps it would over the labels
+    themselves, round for round; a background pair (0, 0) and a repeat of
+    a pair change no step, so only the linking pairs take part
+    (:func:`_linking_pairs`)."""
+    b, hp, _ = lab.shape
+    rows = jnp.concatenate([lab[:, r, :] for r in _seam_rows(hp, band_rows)],
+                           axis=1)
+    keys = jnp.where(rows < _INF, rows, 0).reshape(b, -1)
+    ids, ordered, first = _number(keys)
+    above, below = jnp.split(ids, 2, axis=1)
+    fg_above, fg_below = jnp.split(keys > 0, 2, axis=1)
+    both = fg_above & fg_below
+    a, c, n_linking = _linking_pairs(jnp.where(both, above, 0),
+                                     jnp.where(both, below, 0), both)
+    resolve = jax.vmap(functools.partial(_resolve, size=keys.shape[1]))
+    short = min(_SHORT_PAIRS, a.shape[1])
+    table, rounds = jax.lax.cond(
+        jnp.max(n_linking) <= short,
+        lambda: resolve(a[:, :short], c[:, :short]), lambda: resolve(a, c))
+    root = jnp.take_along_axis(
+        ordered, jnp.take_along_axis(table, ids, axis=1), axis=1)
+    seam_labels = jnp.sum(first & (ordered > 0), axis=1, dtype=jnp.int32)
+    return (jnp.where(keys > 0, root, 0),
+            jnp.stack([rounds, seam_labels], axis=1))
 
 
 @functools.partial(jax.jit, static_argnames=("band_rows", "h", "w",
                                               "interpret"))
-def ccl_relabel(lab: Array, table: Array, passes: Array, rounds: Array, *,
+def ccl_relabel(lab: Array, root: Array, passes: Array, counts: Array, *,
                 band_rows: int, h: int, w: int,
                 interpret: bool) -> CCLSummary:
-    """Band labels and the merge's table -> the labels of :func:`labels`,
-    cropped to (H, W). A root is a band label at its own pixel that the
-    merge left at itself; ``rank`` counts roots in row-major order, and
-    the band kernel spreads each component's least rank, its root's.
-    ``diag`` carries each image's labelling passes (summed over bands)
-    and merge rounds."""
+    """Band labels and the seam rows' roots -> the labels of
+    :func:`labels`, cropped to (H, W). A root is a band label at its own
+    pixel, unless the merge gave it a lesser root on a seam row; ``rank``
+    counts roots in row-major order, and the band kernel spreads each
+    component's least rank, its root's. ``diag`` carries each image's
+    labelling passes (summed over bands), merge rounds and seam labels."""
     b, hp, wp = lab.shape
     fg = lab < _INF
     pos = (jax.lax.broadcasted_iota(jnp.int32, (hp, wp), 0) * wp
            + jax.lax.broadcasted_iota(jnp.int32, (hp, wp), 1) + 1)
-    is_root = fg & (lab == pos) & (table[:, 1:].reshape(lab.shape) == pos)
-    rank = jnp.cumsum(is_root.reshape(b, -1), axis=1, dtype=jnp.int32)
+    is_root = (fg & (lab == pos)).reshape(b, -1)
+    rows = _seam_rows(hp, band_rows)
+    keys = jnp.concatenate([lab[:, r, :] for r in rows], axis=1).reshape(b, -1)
+    merged = (keys < _INF) & (root != keys)
+    is_root = is_root.at[jnp.arange(b)[:, None],
+                         jnp.where(merged, keys - 1, hp * wp)].set(
+                             False, mode="drop")
+    rank = jnp.cumsum(is_root, axis=1, dtype=jnp.int32)
     seeds = jnp.where(fg, rank.reshape(lab.shape), _INF)
     # a component's pixels on a seam row start from its root's rank
-    for rows in (slice(band_rows - 1, hp - 1, band_rows),
-                 slice(band_rows, hp, band_rows)):
-        part = lab[:, rows, :]
-        keys = jnp.where(part < _INF, part, 0).reshape(b, -1)
-        root = jnp.take_along_axis(table, keys, axis=1)
-        at_root = jnp.take_along_axis(rank, jnp.maximum(root - 1, 0), axis=1)
-        seeds = seeds.at[:, rows, :].set(
-            jnp.where(part < _INF, at_root.reshape(part.shape), _INF))
+    at_root = jnp.take_along_axis(rank, jnp.maximum(root - 1, 0), axis=1)
+    for r, part in zip(rows, jnp.split(
+            jnp.where(keys < _INF, at_root, _INF), 2, axis=1)):
+        seeds = seeds.at[:, r, :].set(part.reshape(b, -1, wp))
     spread, _ = _spread(seeds, band_rows=band_rows, name="ccl_rank_spread",
                         interpret=interpret)
-    diag = jnp.stack([passes[:, :, 0, 0].sum(axis=1), rounds], axis=1)
+    diag = jnp.concatenate([passes[:, :, 0, :1].sum(axis=1), counts], axis=1)
     return CCLSummary(labels=jnp.where(fg, spread, 0)[:, :h, :w],
                       n_components=rank[:, -1], diag=diag)
 
@@ -490,6 +556,6 @@ def labels_bands(stack: Array, rows: int, *,
     _, h, w = stack.shape
     interpret = resolve_interpret(interpret)
     lab, passes = ccl_band_labels(stack, band_rows=rows, interpret=interpret)
-    table, rounds = ccl_seam_merge(lab, band_rows=rows)
-    return ccl_relabel(lab, table, passes, rounds, band_rows=rows, h=h, w=w,
+    root, counts = ccl_seam_merge(lab, band_rows=rows)
+    return ccl_relabel(lab, root, passes, counts, band_rows=rows, h=h, w=w,
                        interpret=interpret)
